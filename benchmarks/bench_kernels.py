@@ -106,7 +106,7 @@ def run(quick: bool = True) -> list[str]:
     pvids, live = lire._page_slot_live(state, flat)
     kpage = min(10, cfg.block_size)  # per-page k, clamped like the search path
     pq = jax.jit(lambda q: scan_ops.scan_posting_blocks_topk(
-        q, flat, live, state.pool.blocks, k=kpage, interpret=True))
+        q, flat, live, state.pool.blocks, k=kpage))
     t_pq = _timeit(pq, pqueries, reps=2)
     out.append(
         f"kernel/scan_per_query_topk,{t_pq * 1e6:.1f},"
@@ -118,7 +118,7 @@ def run(quick: bool = True) -> list[str]:
     )
     _, ulive = lire._page_slot_live(state, uniqb)
     bt = jax.jit(lambda q: scan_ops.scan_unique_blocks_topk(
-        q, uniqb, ulive, state.pool.blocks, k=kpage, interpret=True))
+        q, uniqb, ulive, state.pool.blocks, k=kpage))
     t_bt = _timeit(bt, pqueries, reps=2)
     out.append(
         f"kernel/scan_batched_topk,{t_bt * 1e6:.1f},"

@@ -56,6 +56,10 @@ def main() -> None:
                          "'scenario', replicas for 'replica', serve for "
                          "'serve', else search")
     args = ap.parse_args()
+    if not args.dry:
+        from repro.utils.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
 
     if args.json:
         import os

@@ -64,21 +64,22 @@ class AnalysisSpec:
 # The repo's own spec
 # ---------------------------------------------------------------------------
 
-# Reference serving shape for the VMEM table: the TPU-target geometry the
-# kernel docstrings reason about (LireConfig defaults: dim=128, block_size
-# =16, nprobe=8 → nb = nprobe * max_blocks_per_posting = 64 pages), a
-# 256-query navigation tile, and the l2_topk defaults (block_q=128,
-# block_p=512 over a 4096-centroid shard).  BENCH_search.json's CPU
-# traffic model runs far smaller shapes; this is the budget-sizing shape.
+# Reference serving shape for the VMEM table: the per-shard production
+# geometry of spfresh-1b (configs/spfresh.py CONFIG_PAGED: d=100 int8
+# pages of 32 vectors, nprobe=64 over 4-page postings → nb = 256 pages
+# per query, k=10 candidates per page, a 1024-query search batch, 65,536
+# centroids per shard) and the l2_topk tile defaults (block_q=128,
+# block_p=512).  One ``k`` serves every kernel: the scan's per-page k;
+# navigation's per-tile k is nprobe, which the estimate understates.
 VMEM_BINDINGS = {
-    "dim": 128,        # vector dimension
-    "bs": 16,          # block_size: vectors per SSD page
-    "k": 8,            # per-page / per-tile candidates kept
-    "q_n": 256,        # queries per micro-batch dispatch
-    "nb": 64,          # pages per query (nprobe * max_blocks_per_posting)
+    "dim": 100,        # vector dimension
+    "bs": 32,          # block_size: vectors per SSD page
+    "k": 10,           # per-page / per-tile candidates kept
+    "q_n": 1024,       # queries per micro-batch dispatch
+    "nb": 256,         # pages per query (nprobe * max_blocks_per_posting)
     "block_q": 128,    # l2_topk query tile
     "block_p": 512,    # l2_topk centroid tile
-    "p_n": 4096,       # centroids per shard (l2_topk input rows)
+    "p_n": 65536,      # centroids per shard (l2_topk input rows)
 }
 
 DEFAULT_SPEC = AnalysisSpec(
@@ -107,8 +108,17 @@ DEFAULT_SPEC = AnalysisSpec(
         budget_bytes=16 * 1024 * 1024,   # VMEM per TensorCore (~16 MiB)
         bindings=VMEM_BINDINGS,
         dtype_overrides={
-            # int8 code pages: in_specs index 1 is the block-pool operand
-            ("repro.kernels.posting_scan.kernel", "scan_per_query_topk_q8"):
+            # int8 pages (the production pool stores int8 payloads under
+            # every codec): in_specs index 1 is the block-pool operand
+            ("repro.kernels.posting_scan.kernel", "_scan_per_query_rows"):
+                {1: "int8"},
+            ("repro.kernels.posting_scan.kernel", "scan_batched"):
+                {1: "int8"},
+            ("repro.kernels.posting_scan.kernel",
+             "_scan_per_query_topk_rows"): {1: "int8"},
+            ("repro.kernels.posting_scan.kernel",
+             "_scan_per_query_topk_q8_rows"): {1: "int8"},
+            ("repro.kernels.posting_scan.kernel", "scan_batched_topk"):
                 {1: "int8"},
             ("repro.kernels.posting_scan.kernel", "scan_batched_topk_q8"):
                 {1: "int8"},

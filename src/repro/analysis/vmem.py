@@ -265,6 +265,7 @@ def _analyze_site(
                     isinstance(node.targets[0], ast.Name) and \
                     node.targets[0].id == gs.id:
                 gs = node.value
+                break
     if isinstance(gs, ast.Call):
         grid_node = _kw(gs, "grid") or grid_node
         in_specs = _kw(gs, "in_specs") or in_specs

@@ -52,7 +52,6 @@ class ScanSpec:
     use_pallas_scan: bool | None = None
     scan_schedule: str | None = None       # "per_query" | "batched" | None
     scan_page_budget: int | None = None
-    pallas_interpret: bool | None = None
     # Posting payload codec (storage/codec.py): "fp32" | "bf16" | "int8";
     # None defers to IndexSpec.config.  Lossy codecs over-fetch
     # rerank_factor×k quantized candidates and rerank them against the
@@ -216,7 +215,6 @@ class ServiceSpec:
             ("use_pallas_scan", s.use_pallas_scan),
             ("scan_schedule", s.scan_schedule),
             ("scan_page_budget", s.scan_page_budget),
-            ("pallas_interpret", s.pallas_interpret),
             ("codec", s.codec),
             ("rerank_factor", s.rerank_factor),
             ("jobs_per_round", m.jobs_per_round),

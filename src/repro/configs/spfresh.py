@@ -70,12 +70,14 @@ UPDATE_B = 4096
 PROBE_CHUNK = 0
 
 # Paged-scan production path (serve_search_paged): the batch-dedup Pallas
-# schedule with a static page budget.  SEARCH_Q·nprobe probes touch at most
-# num_blocks distinct pages; 32768 (= num_blocks/8) caps the kernel grid
-# while staying above the unique-page count of real probe distributions
-# (overflow drops the highest-numbered pages, counted by dedup_pages).
-# pallas_interpret stays True so the cell lowers everywhere; flip it off on
-# real TPU hardware.
+# schedule with a static page budget.  32768 (= num_blocks/8) caps the
+# kernel grid.  A micro-batch of Q queries probes at most Q·nprobe·MB
+# pages, and pages past the budget are dropped (counted by dedup_pages):
+# at Q=1024 queries spread over a 1M-vector shard that is most of them.
+# The paged service spec therefore caps its micro-batch at
+# budget / (nprobe·MB) = 128 queries, where no probe can be dropped.
+# The kernels compile on a TPU and run the Pallas interpreter on the CPU
+# (repro.kernels.backend), so the same config serves both.
 CONFIG_PAGED = dataclasses.replace(
     CONFIG,
     use_pallas_scan=True,
@@ -106,10 +108,14 @@ def service_spec(*, paged: bool = True, smoke: bool = False,
     import spfresh
 
     base = SMOKE if smoke else (CONFIG_PAGED if paged else CONFIG)
+    max_batch = SEARCH_Q
+    if base.use_pallas_scan and base.scan_page_budget:
+        max_batch = min(max_batch, base.scan_page_budget // (
+            base.nprobe * base.max_blocks_per_posting))
     return spfresh.ServiceSpec(
         index=spfresh.IndexSpec(config=base),
         serve=spfresh.ServeSpec(
-            search_k=10, nprobe=base.nprobe, max_batch=SEARCH_Q,
+            search_k=10, nprobe=base.nprobe, max_batch=max_batch,
             max_lag=max_lag,
         ),
         scan=spfresh.ScanSpec(probe_chunk=PROBE_CHUNK),

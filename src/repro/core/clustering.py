@@ -96,6 +96,12 @@ def balanced_kmeans(
     return centroids, assign
 
 
+def pow2_bucket(n: int) -> int:
+    """Smallest power of two ``>= max(n, 64)``: the padded row count that
+    bounds how many shapes a host-driven build compiles for."""
+    return max(64, 1 << max(0, int(n) - 1).bit_length())
+
+
 def hierarchical_balanced_kmeans(
     x: np.ndarray,
     *,
@@ -109,7 +115,10 @@ def hierarchical_balanced_kmeans(
 
     Returns ``(centroids (P, d) f32, assign (n,) i32)`` with
     ``max leaf size <= max_posting_size`` (up to degenerate duplicates).
-    Host-driven recursion over jitted :func:`balanced_kmeans`.
+    Host-driven recursion over jitted :func:`balanced_kmeans`.  Each
+    node's rows are padded on the host to a power-of-two bucket and
+    masked by ``valid``, so the build compiles one program per
+    ``(bucket, k)`` pair rather than one per node size.
     """
     x = np.asarray(x, np.float32)
     n = x.shape[0]
@@ -139,12 +148,14 @@ def hierarchical_balanced_kmeans(
             continue
         k = min(branch, max(2, int(np.ceil(idx.size / max_posting_size))))
         key, sub = jax.random.split(key)
-        sub_x = jnp.asarray(x[idx])
-        valid = jnp.ones((idx.size,), bool)
+        bucket = pow2_bucket(idx.size)
+        sub_x = np.zeros((bucket, x.shape[1]), np.float32)
+        sub_x[: idx.size] = x[idx]
         _, a = balanced_kmeans(
-            sub, sub_x, valid, k=k, iters=iters, balance_weight=balance_weight
+            sub, sub_x, np.arange(bucket) < idx.size,
+            k=k, iters=iters, balance_weight=balance_weight,
         )
-        a = np.asarray(a)
+        a = np.asarray(a)[: idx.size]
         split_happened = False
         for c in range(k):
             child = idx[a == c]

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import lire
-from repro.core.clustering import hierarchical_balanced_kmeans
+from repro.core.clustering import hierarchical_balanced_kmeans, pow2_bucket
 from repro.core.distance import pairwise_sql2
 from repro.core.types import IndexState, LireConfig, make_empty_state
 from repro.storage import codec as pcodec
@@ -37,6 +37,15 @@ _INSERT_CHUNK = 256
 _QUERY_CHUNK = 64
 
 
+@functools.partial(jax.jit, static_argnames=("r",))
+def _nearest_centroids(xs, cen, cen_valid, *, r: int):
+    """``(dists (n, r), pids (n, r))``: each row's r nearest valid
+    centroids, nearest first."""
+    d = jnp.where(cen_valid[None, :], pairwise_sql2(xs, cen), jnp.inf)
+    neg_d, idx = jax.lax.top_k(-d, r)
+    return -neg_d, idx
+
+
 def _build_routing(
     vectors: np.ndarray,
     centroids: np.ndarray,
@@ -45,33 +54,39 @@ def _build_routing(
     chunk: int = 8192,
 ) -> list[list[int]]:
     """Vector → posting membership lists: primary (from the clustering) plus
-    SPANN closure replicas (top-R centroids within the replica_rng ratio)."""
+    SPANN closure replicas (top-R centroids within the replica_rng ratio).
+
+    Replicas are admitted in vid order while a posting stays within
+    ``split_limit``: a fresh build hands the rebuilder no split backlog.
+    The centroid table and the last chunk are padded (centroids to a
+    power-of-two bucket, masked), so routing compiles one program."""
     n = vectors.shape[0]
     p = centroids.shape[0]
     members: list[list[int]] = [[] for _ in range(p)]
-    for i in range(n):
-        members[int(assign[i])].append(i)
+    for i, pid in enumerate(assign.tolist()):
+        members[pid].append(i)
 
     if cfg.replica_count > 1 and p > 1:
         r = min(cfg.replica_count, p)
-        cen = jnp.asarray(centroids, jnp.float32)
+        pb = pow2_bucket(p)
+        cen = np.zeros((pb, centroids.shape[1]), np.float32)
+        cen[:p] = centroids
+        cen_valid = np.arange(pb) < p
         factor = float(cfg.replica_rng) ** 2
-        cap = cfg.posting_capacity
+        cap = cfg.split_limit
         for start in range(0, n, chunk):
-            xs = jnp.asarray(vectors[start : start + chunk], jnp.float32)
-            d = pairwise_sql2(xs, cen)
-            neg_d, idx = jax.lax.top_k(-d, r)
-            dists = np.asarray(-neg_d)
-            idx = np.asarray(idx)
-            for row in range(idx.shape[0]):
-                vid = start + row
-                dmin = dists[row, 0]
-                for j in range(r):
-                    pid = int(idx[row, j])
-                    if pid == int(assign[vid]):
-                        continue
-                    if dists[row, j] <= factor * dmin and len(members[pid]) < cap:
-                        members[pid].append(vid)
+            rows = vectors[start : start + chunk]
+            xs = np.zeros((chunk, vectors.shape[1]), np.float32)
+            xs[: len(rows)] = rows
+            dists, idx = _nearest_centroids(xs, cen, cen_valid, r=r)
+            dists = np.asarray(dists)[: len(rows)]
+            idx = np.asarray(idx)[: len(rows)]
+            own = assign[start : start + len(rows)]
+            ok = (idx != own[:, None]) & (dists <= factor * dists[:, :1])
+            for row, j in zip(*np.nonzero(ok)):
+                pid = int(idx[row, j])
+                if len(members[pid]) < cap:
+                    members[pid].append(start + int(row))
     return members
 
 

@@ -52,8 +52,7 @@ def navigate(state: IndexState, queries: Array, nprobe: int) -> tuple[Array, Arr
         from repro.kernels.l2_topk.ops import l2_topk
 
         d, idx = l2_topk(
-            queries, state.centroids, state.centroid_valid, k=nprobe,
-            interpret=state.cfg.pallas_interpret,
+            queries, state.centroids, state.centroid_valid, k=nprobe
         )
         d = jnp.where(idx >= 0, d, MASK_DISTANCE)
         return d, idx
@@ -327,7 +326,6 @@ def _pallas_scan_candidates(
     mb = pool.max_blocks_per_posting
     bs = pool.block_size
     kpage = min(k, pool.block_size)
-    interp = cfg.pallas_interpret
     quant = pool.codec == "int8"
     flat = _page_table(state, pids, probe_valid)        # (Q, NB)
     # posting owning each page row: pages j of probe i are i*MB..i*MB+MB-1
@@ -340,11 +338,11 @@ def _pallas_scan_candidates(
             d, slots = scan_ops.scan_posting_blocks_topk_q8(
                 queries, flat, live, pool.blocks,
                 pool.post_scale[safe_pp], pool.post_zero[safe_pp],
-                k=kpage, interpret=interp,
+                k=kpage,
             )                                           # (Q, NB, kpage)
         else:
             d, slots = scan_ops.scan_posting_blocks_topk(
-                queries, flat, live, pool.blocks, k=kpage, interpret=interp
+                queries, flat, live, pool.blocks, k=kpage
             )                                           # (Q, NB, kpage)
         cand_v = jnp.take_along_axis(pvids, slots, axis=2)
         cand_p = jnp.where(
@@ -374,16 +372,12 @@ def _pallas_scan_candidates(
             )
             d, slots = scan_ops.scan_unique_blocks_topk_q8(
                 queries, uniq, live, pool.blocks, u_scale, u_zero,
-                k=kpage, interpret=interp,
-            )                                           # (budget, Q, kpage)
+                k=kpage,
+            )                                           # (budget, kpage, Q)
         else:
             d, slots = scan_ops.scan_unique_blocks_topk(
-                queries, uniq, live, pool.blocks, k=kpage, interpret=interp
-            )                                           # (budget, Q, kpage)
-        page_v = jnp.take_along_axis(pvids[:, None, :], slots, axis=2)
-        page_p = jnp.where(
-            (uniq >= 0)[:, None, None], uniq[:, None, None] * bs + slots, -1
-        )
+                queries, uniq, live, pool.blocks, k=kpage
+            )                                           # (budget, kpage, Q)
         # gather each query's own probed pages back out of the unique-page
         # tiles (parity with the per-query schedule: a page another query
         # probed must not leak in) — the reduce then sees the per-query
@@ -391,12 +385,15 @@ def _pallas_scan_candidates(
         mp = member_pos.reshape(q, -1)                  # (Q, NB)
         safe_mp = jnp.maximum(mp, 0)
         qi = jnp.arange(q)[:, None]
+        slot_q = slots[safe_mp, :, qi]                  # (Q, NB, kpage)
         cand_d = jnp.where(
-            (mp >= 0)[:, :, None], d[safe_mp, qi], MASK_DISTANCE
+            (mp >= 0)[:, :, None], d[safe_mp, :, qi], MASK_DISTANCE
         ).reshape(q, -1)
-        cand_v = page_v[safe_mp, qi].reshape(q, -1)
+        cand_v = jnp.take_along_axis(pvids[safe_mp], slot_q, axis=2)
+        cand_v = cand_v.reshape(q, -1)
         cand_p = jnp.where(
-            (mp >= 0)[:, :, None], page_p[safe_mp, qi], -1
+            (mp >= 0)[:, :, None], uniq[safe_mp][:, :, None] * bs + slot_q,
+            -1,
         ).reshape(q, -1)
     else:
         raise ValueError(

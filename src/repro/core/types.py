@@ -76,7 +76,8 @@ class LireConfig:
     enable_split: bool = True
     enable_merge: bool = True
     enable_reassign: bool = True
-    # --- kernel integration (TPU target; interpret=True executes on CPU) ---
+    # --- kernel integration (compiled on a TPU, interpreted elsewhere:
+    # repro.kernels.backend decides from the platform) ---
     use_pallas_nav: bool = False
     # Paged Pallas posting scan (search hot path).  False = XLA gather
     # oracle (`bp.parallel_get` + diff²), the default on CPU.  True streams
@@ -96,7 +97,6 @@ class LireConfig:
     # smaller explicit budget bounds the kernel grid, dropping the
     # highest-numbered pages on overflow (counted, see `dedup_pages`).
     scan_page_budget: int = 0
-    pallas_interpret: bool = True
 
     @property
     def posting_capacity(self) -> int:

@@ -51,6 +51,30 @@ def make_spacev_like(n: int, dim: int = 16, seed: int = 0) -> np.ndarray:
     return _clustered(rng, n, dim, n_clusters=k, weights=w, drift=0.5)
 
 
+def make_spacev_bytes(
+    n: int, dim: int = 100, seed: int = 0, *, latent: int = 8,
+    n_clusters: int | None = None,
+) -> np.ndarray:
+    """SPACEV1B-shaped byte vectors: Zipf-skewed cluster masses (as
+    :func:`make_spacev_like`), each cluster spread along its own
+    ``latent``-dimensional subspace (real embeddings have a low intrinsic
+    dimension; isotropic clusters at d=100 have no near neighbours),
+    scaled and rounded into [-127, 127] so an int8 payload stores them
+    losslessly.  Returned as float32 holding integers."""
+    rng = np.random.default_rng(seed)
+    k = n_clusters or max(8, n // 500)
+    w = 1.0 / np.arange(1, k + 1) ** 1.2
+    assign = rng.choice(k, size=n, p=w / w.sum())
+    centers = 25.0 * rng.normal(size=(k, dim)).astype(np.float32)
+    x = centers[assign]
+    for c in np.unique(assign):
+        rows = np.nonzero(assign == c)[0]
+        basis, _ = np.linalg.qr(rng.normal(size=(dim, latent)))
+        z = 20.0 * rng.normal(size=(rows.size, latent))
+        x[rows] += (z @ basis.T).astype(np.float32)
+    return np.clip(np.rint(x), -127, 127).astype(np.float32)
+
+
 def make_shifting_stream(
     n: int, dim: int = 16, seed: int = 0, hot_fraction: float = 0.7
 ) -> np.ndarray:

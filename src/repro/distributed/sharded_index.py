@@ -39,16 +39,10 @@ Array = jax.Array
 
 
 def _shard_map(f, *, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions (new API vs experimental)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    """``jax.shard_map`` with the varying-manual-axes check off: the
+    per-shard LIRE ops mix shard-local and replicated values freely."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False,
     )
 
 
@@ -57,19 +51,26 @@ def _shard_map(f, *, mesh, in_specs, out_specs):
 # ---------------------------------------------------------------------------
 
 def stack_states(states: list[IndexState]) -> IndexState:
-    """Stack per-shard states along a new leading axis (P('model'))."""
-    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs, axis=0), *states)
+    """Stack per-shard states along a new leading axis (P('model')), on
+    the host: ``ShardedIndex`` then places each shard on its own device,
+    so no device ever holds the whole stack."""
+    return jax.tree_util.tree_map(
+        lambda *xs: np.stack([np.asarray(x) for x in xs], axis=0), *states
+    )
+
+
+def stacked_template(cfg: LireConfig, n_shards: int) -> IndexState:
+    """Abstract (shape/dtype only) stacked state — the snapshot template
+    for recovery, built without touching a device."""
+    abstract = jax.eval_shape(lambda: make_empty_state(cfg))
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct((n_shards, *x.shape), x.dtype),
+        abstract,
+    )
 
 
 def unstack_state(stacked: IndexState, i: int) -> IndexState:
     return jax.tree_util.tree_map(lambda x: x[i], stacked)
-
-
-def state_pspecs(stacked: IndexState) -> Any:
-    """P('model', None, ...) for every leaf of the stacked state."""
-    return jax.tree_util.tree_map(
-        lambda x: P("model", *([None] * (x.ndim - 1))), stacked
-    )
 
 
 def _squeeze(tree):
@@ -88,18 +89,11 @@ def _data_axes(mesh: Mesh):
 # Distributed search
 # ---------------------------------------------------------------------------
 
-def _axis_size(a):
-    """jax.lax.axis_size compat (older jax: psum of ones)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(a)
-    return jax.lax.psum(1, a)
-
-
 def _flat_axis_index(axes):
     """Flattened linear index over one or more mesh axes (row-major)."""
     idx = jax.lax.axis_index(axes[0])
     for a in axes[1:]:
-        idx = idx * _axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
@@ -380,7 +374,10 @@ def build_sharded_state(
             st = build_state(cfg, vectors[idx], seed=seed + s)
             st = st.replace(next_vid=jnp.asarray(len(idx), jnp.int32))
             handles[idx] = s * cfg.num_vectors_cap + np.arange(len(idx))
-        states.append(st)
+        # each shard moves to the host as soon as it is built: the build
+        # runs on one device, which holds a single shard at a time
+        states.append(jax.device_get(st))
+        del st
     return stack_states(states), handles
 
 
@@ -392,6 +389,9 @@ def gather_live_vectors(
     from repro.storage import versionmap as vm
 
     out_v, out_h = [], []
+    # one host copy, then plain numpy slicing: indexing a shard out of a
+    # mesh-sharded device array has no unambiguous output sharding
+    stacked = jax.device_get(stacked)
     for s in range(n_shards):
         st = unstack_state(stacked, s)
         vids = np.asarray(st.pool.block_vid).reshape(-1)
@@ -458,9 +458,9 @@ class ShardedIndex(DurableBackend):
     ):
         self.mesh = mesh
         self.cfg = cfg
-        self.stacked = stacked
         self.n_shards = n_shards
         self.shard_axes = shard_axes
+        self.adopt_state(stacked)
         self.probe_chunk = probe_chunk
         self.use_pallas_scan = use_pallas_scan
         self.scan_schedule = scan_schedule
@@ -504,10 +504,12 @@ class ShardedIndex(DurableBackend):
         return jax.tree_util.tree_map(jnp.copy, self.stacked)
 
     def adopt_state(self, stacked: IndexState) -> None:
-        """Install a (forked) stacked state, re-placed onto THIS index's
-        mesh — the replica rows of a (data, model) mesh each run their
-        own single-axis submesh (see ``sharding.replica_submeshes``)."""
-        specs = state_pspecs(stacked)
+        """Install a stacked state (host-built, restored, or forked),
+        placed onto THIS index's mesh: shard i on the i-th device of the
+        shard axes.  The replica rows of a (data, model) mesh each run
+        their own single-axis submesh (see
+        ``sharding.replica_submeshes``)."""
+        specs = state_pspecs_for(self.cfg, self.shard_axes)
         shardings = jax.tree_util.tree_map(
             lambda s: NamedSharding(self.mesh, s), specs,
             is_leaf=lambda x: isinstance(x, P),
@@ -519,13 +521,12 @@ class ShardedIndex(DurableBackend):
         same config and step geometry, its own deep-copied state, its own
         compiled steps."""
         twin = ShardedIndex(
-            mesh or self.mesh, self.cfg, self.stacked, self.n_shards,
+            mesh or self.mesh, self.cfg, self.fork_state(), self.n_shards,
             shard_axes=self.shard_axes, probe_chunk=self.probe_chunk,
             use_pallas_scan=self.use_pallas_scan,
             scan_schedule=self.scan_schedule,
             jobs_per_round=self.jobs_per_round,
         )
-        twin.adopt_state(self.fork_state())
         twin._wal_applied = self._wal_applied
         return twin
 
@@ -682,9 +683,7 @@ class ShardedIndex(DurableBackend):
         ``replay``)."""
         from repro.storage.snapshot import SnapshotStore
 
-        template = stack_states(
-            [make_empty_state(cfg) for _ in range(n_shards)]
-        )
+        template = stacked_template(cfg, n_shards)
         stacked, manifest = SnapshotStore(snapshot_dir).load(template)
         extra = manifest.get("extra", {})
         if extra.get("n_shards", n_shards) != n_shards:

@@ -51,27 +51,10 @@ def replica_submeshes(mesh, replica_axis: str = "data"):
 
 
 def current_mesh():
-    """The ambient (abstract) mesh, across jax versions.
-
-    Newer jax: ``jax.sharding.get_abstract_mesh()`` (set via
-    ``jax.set_mesh``).  Older jax: the physical mesh installed by the
-    ``with mesh:`` context.  Returns None when no mesh is active.
-    """
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is not None:
-        am = get_am()
-        return am if am.axis_names else None
-    from jax._src import mesh as mesh_lib
-
-    pm = mesh_lib.thread_resources.env.physical_mesh
-    return pm if pm.axis_names else None
-
-
-def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` on newer jax, ``with mesh:`` on older."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh  # Mesh is itself a context manager on older jax
+    """The ambient abstract mesh set by ``jax.set_mesh``, or None when no
+    mesh is active."""
+    am = jax.sharding.get_abstract_mesh()
+    return am if am.axis_names else None
 
 
 def _axes_size(am, entry) -> int:

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.backend import resolve_interpret
+
 # Plain Python float: a jnp scalar would be a captured traced constant,
 # which pallas_call rejects.
 BIG = 3.0e38
@@ -33,24 +35,32 @@ def _l2_topk_kernel(q_ref, c_ref, csq_ref, out_d_ref, out_i_ref, *, k: int,
     pi = pl.program_id(1)
     q = q_ref[...].astype(jnp.float32)           # (BQ, d)
     c = c_ref[...].astype(jnp.float32)           # (BP, d)
-    csq = csq_ref[0, :]                          # (BP,) f32 (BIG if invalid)
+    csq = csq_ref[...]                           # (1, BP) f32 (BIG if invalid)
 
     qsq = jnp.sum(q * q, axis=1, keepdims=True)  # (BQ, 1)
     cross = jax.lax.dot_general(
         q, c, dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )                                            # (BQ, BP)
-    d = qsq - 2.0 * cross + csq[None, :]
+    d = qsq - 2.0 * cross + csq
 
     bq = d.shape[0]
     col = jax.lax.broadcasted_iota(jnp.int32, (bq, block_p), 1)
-    # Unrolled k-min extraction (k is small: nprobe candidates per tile).
+    slot = jax.lax.broadcasted_iota(jnp.int32, (bq, k), 1)
+    kd = jnp.zeros((bq, k), jnp.float32)
+    ki = jnp.zeros((bq, k), jnp.int32)
+    # Unrolled k-min extraction (k is small: nprobe candidates per tile);
+    # the arg-min is the first column at the minimum, and the (BQ, k)
+    # tile is assembled with selects and stored once.
     for j in range(k):
-        m = jnp.min(d, axis=1)
-        a = jnp.argmin(d, axis=1).astype(jnp.int32)
-        out_d_ref[:, j] = m
-        out_i_ref[:, j] = a + pi * block_p
-        d = jnp.where(col == a[:, None], BIG, d)
+        m = jnp.min(d, axis=1, keepdims=True)
+        a = jnp.min(jnp.where(d == m, col, block_p), axis=1, keepdims=True)
+        kd = jnp.where(slot == j, m, kd)
+        ki = jnp.where(slot == j, a, ki)
+        d = jnp.where(col == a, BIG, d)
+    out_d_ref[0] = kd
+    out_i_ref[0] = ki + pi * block_p
 
 
 @functools.partial(
@@ -65,10 +75,15 @@ def l2_topk_tiles(
     k: int,
     block_q: int = 128,
     block_p: int = 512,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Per-tile candidates: ``(dists (Q, T*k), indices (Q, T*k))`` where
-    T = P/block_p.  Final global top-k is done by the caller."""
+    T = P/block_p.  Final global top-k is done by the caller.
+
+    The kernel writes tile-major ``(T, Q, k)`` blocks — a ``(block_q, k)``
+    block of a ``(Q, T*k)`` array would break the TPU's (8, 128) block
+    rule whenever ``k`` is not a multiple of 128 — and the wrapper lays
+    them out query-major afterwards."""
     q_n, dim = queries.shape
     p_n = centroids.shape[0]
     assert q_n % block_q == 0 and p_n % block_p == 0, (q_n, p_n)
@@ -84,13 +99,16 @@ def l2_topk_tiles(
             pl.BlockSpec((1, block_p), lambda qi, pi: (0, pi)),
         ],
         out_specs=[
-            pl.BlockSpec((block_q, k), lambda qi, pi: (qi, pi)),
-            pl.BlockSpec((block_q, k), lambda qi, pi: (qi, pi)),
+            pl.BlockSpec((1, block_q, k), lambda qi, pi: (pi, qi, 0)),
+            pl.BlockSpec((1, block_q, k), lambda qi, pi: (pi, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((q_n, t * k), jnp.float32),
-            jax.ShapeDtypeStruct((q_n, t * k), jnp.int32),
+            jax.ShapeDtypeStruct((t, q_n, k), jnp.float32),
+            jax.ShapeDtypeStruct((t, q_n, k), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(queries, centroids, c_sqn)
-    return out_d, out_i
+    return (
+        out_d.transpose(1, 0, 2).reshape(q_n, t * k),
+        out_i.transpose(1, 0, 2).reshape(q_n, t * k),
+    )

@@ -24,7 +24,7 @@ def l2_topk(
     k: int,
     block_q: int = 128,
     block_p: int = 512,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Masked k-nearest centroids: ``(dists (Q,k), idx (Q,k))``.
 

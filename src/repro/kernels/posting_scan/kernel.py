@@ -25,6 +25,15 @@ same VPU idiom as ``l2_topk``.  The caller's merge works over
 ``(Q, NB·k)`` candidates instead of the full ``(Q, NB·BS)`` distance
 matrix, which is what lets the search hot path stream pages without ever
 materializing the distance tiles in HBM.
+
+Layout: the TPU compiler requires the last two dims of every block to be
+divisible by (8, 128) or equal to the array's.  Operands whose per-step
+row block would be 1 (a query row, a page's bias row, its [scale, zero]
+pair, a per-query output tile) are therefore reshaped by the wrappers to
+carry a singleton second-to-last axis, so each block's trailing dims
+equal the array's.  The reshapes are free and the public shapes are
+unchanged.  Page norms are taken on the MXU (a ones-row GEMM) so that
+they come out lane-major, next to the (rows, BS) cross term.
 """
 from __future__ import annotations
 
@@ -35,15 +44,115 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import resolve_interpret
+
+# Plain Python float: a jnp scalar would be a captured traced constant,
+# which pallas_call rejects (same trick as l2_topk).
+BIG = 3.0e38
+
+_HI = jax.lax.Precision.HIGHEST
+_NT = (((1,), (1,)), ((), ()))   # contract the last dims: (m, d)·(n, d)ᵀ
+_SMEM_TABLE_BYTES = 256 * 1024
+
+
+def _dot_nt(a, b):
+    """``a (m, d) · b (n, d)ᵀ`` at full f32 precision."""
+    return jax.lax.dot_general(
+        a, b, _NT, precision=_HI, preferred_element_type=jnp.float32
+    )
+
+
+def _page_dists(q, b):
+    """Squared L2 ``(rows, BS)`` between queries ``q (rows, d)`` and one
+    page ``b (BS, d)``, both f32: ‖q‖² − 2 q·b + ‖b‖² clamped at 0.
+    Every term is a dot over d, so `_page_dists_t` computes each entry
+    with the same arithmetic and the two schedules agree bit for bit."""
+    ones = jnp.ones((1, q.shape[1]), jnp.float32)
+    qsq = _dot_nt(q * q, ones)                                 # (rows, 1)
+    bsq = _dot_nt(ones, b * b)                                 # (1, BS)
+    return jnp.maximum(qsq - 2.0 * _dot_nt(q, b) + bsq, 0.0)
+
+
+def _page_dists_t(q, b):
+    """`_page_dists` laid out page-major: ``(BS, rows)``, so that a batch
+    of queries runs along the lanes."""
+    ones = jnp.ones((1, q.shape[1]), jnp.float32)
+    qsq = _dot_nt(ones, q * q)                                 # (1, rows)
+    bsq = _dot_nt(b * b, ones)                                 # (BS, 1)
+    return jnp.maximum(qsq - 2.0 * _dot_nt(b, q) + bsq, 0.0)
+
+
+def _kmin(d, *, k: int, axis: int):
+    """Unrolled k-min of ``d`` along ``axis``: the l2_topk min/mask loop.
+    Returns ``(dists, argmins)`` with ``axis`` cut to ``k``; the arg-min
+    is the first index holding the minimum (``jnp.argmin``'s tie rule).
+    Results are assembled with selects so the kernel stores whole
+    blocks."""
+    n = d.shape[axis]
+    idx = jax.lax.broadcasted_iota(jnp.int32, d.shape, axis)
+    out_shape = d.shape[:axis] + (k,) + d.shape[axis + 1:]
+    slot = jax.lax.broadcasted_iota(jnp.int32, out_shape, axis)
+    kd = jnp.zeros(out_shape, jnp.float32)
+    ki = jnp.zeros(out_shape, jnp.int32)
+    for j in range(k):
+        m = jnp.min(d, axis=axis, keepdims=True)
+        a = jnp.min(jnp.where(d == m, idx, n), axis=axis, keepdims=True)
+        kd = jnp.where(slot == j, m, kd)
+        ki = jnp.where(slot == j, a, ki)
+        d = jnp.where(idx == a, BIG, d)
+    return kd, ki
+
+
+def _dequant(codes, sz):
+    """One int8 code page ``(BS, d)`` decoded on the VPU with its
+    posting's ``sz (1, 2)`` = [scale, zero]."""
+    return codes.astype(jnp.float32) * sz[:, 0:1] + sz[:, 1:2]
+
+
+def _by_query_chunks(call, block_table, queries, blocks, *row_operands):
+    """Run a per-query-schedule ``call(table, queries, blocks, *rows)``
+    once per query chunk and concatenate its outputs along the query
+    axis.  The ``(Q, NB)`` block table is a scalar-prefetch operand and
+    SMEM holds 1 MiB, so each call takes at most ``_SMEM_TABLE_BYTES`` of
+    table.  ``row_operands`` are per-query and sliced with the table."""
+    q_n, nb = block_table.shape
+    rows = max(1, _SMEM_TABLE_BYTES // (4 * nb))
+    outs = [
+        call(block_table[s:s + rows], queries[s:s + rows], blocks,
+             *(x[s:s + rows] for x in row_operands))
+        for s in range(0, q_n, rows)
+    ]
+    if len(outs) == 1:
+        return outs[0]
+    return jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs), *outs)
+
 
 def _scan_per_query_kernel(table_ref, q_ref, blk_ref, out_ref):
-    # q_ref: (1, d); blk_ref: (1, BS, d); out: (1, 1, BS)
-    q = q_ref[0, :].astype(jnp.float32)
-    b = blk_ref[0].astype(jnp.float32)            # (BS, d)
-    bsq = jnp.sum(b * b, axis=1)                  # (BS,)
-    cross = jnp.dot(b, q, preferred_element_type=jnp.float32)  # (BS,)
-    qsq = jnp.sum(q * q)
-    out_ref[0, 0, :] = jnp.maximum(qsq - 2.0 * cross + bsq, 0.0)
+    # q_ref: (1, 1, d); blk_ref: (1, BS, d); out: (1, 1, 1, BS)
+    q = q_ref[0].astype(jnp.float32)               # (1, d)
+    b = blk_ref[0].astype(jnp.float32)             # (BS, d)
+    out_ref[0, 0] = _page_dists(q, b)
+
+
+def _scan_per_query_rows(block_table, queries, blocks, *, interpret):
+    q_n, nb = block_table.shape
+    _, bs, dim = blocks.shape
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(q_n, nb),
+        in_specs=[
+            pl.BlockSpec((1, 1, dim), lambda q, j, table: (q, 0, 0)),
+            pl.BlockSpec((1, bs, dim), lambda q, j, table: (table[q, j], 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, 1, 1, bs), lambda q, j, table: (q, j, 0, 0)),
+    )
+    out = pl.pallas_call(
+        _scan_per_query_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((q_n, nb, 1, bs), jnp.float32),
+        interpret=resolve_interpret(interpret),
+    )(block_table, queries[:, None, :], blocks)
+    return out.reshape(q_n, nb, bs)
 
 
 @functools.partial(
@@ -54,39 +163,20 @@ def scan_per_query(
     queries: jax.Array,      # (Q, d)
     blocks: jax.Array,       # (B, BS, d) — the block pool payload
     *,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Distances (Q, NB, BS): page j of query q scored against query q."""
-    q_n, nb = block_table.shape
-    _, bs, dim = blocks.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(q_n, nb),
-        in_specs=[
-            pl.BlockSpec((1, dim), lambda q, j, table: (q, 0)),
-            pl.BlockSpec((1, bs, dim), lambda q, j, table: (table[q, j], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, bs), lambda q, j, table: (q, j, 0)),
+    return _by_query_chunks(
+        functools.partial(_scan_per_query_rows, interpret=interpret),
+        block_table, queries, blocks,
     )
-    return pl.pallas_call(
-        _scan_per_query_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((q_n, nb, bs), jnp.float32),
-        interpret=interpret,
-    )(block_table, queries, blocks)
 
 
 def _scan_batched_kernel(ids_ref, q_ref, blk_ref, out_ref):
-    # q_ref: (Q, d) resident; blk_ref: (1, BS, d); out: (1, Q, BS)
+    # q_ref: (Q, d) resident; blk_ref: (1, BS, d); out: (1, BS, Q)
     q = q_ref[...].astype(jnp.float32)            # (Q, d)
     b = blk_ref[0].astype(jnp.float32)            # (BS, d)
-    qsq = jnp.sum(q * q, axis=1, keepdims=True)   # (Q, 1)
-    bsq = jnp.sum(b * b, axis=1)                  # (BS,)
-    cross = jax.lax.dot_general(
-        q, b, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                             # (Q, BS)
-    out_ref[0] = jnp.maximum(qsq - 2.0 * cross + bsq[None, :], 0.0)
+    out_ref[0] = _page_dists_t(q, b)
 
 
 @functools.partial(
@@ -97,9 +187,10 @@ def scan_batched(
     queries: jax.Array,        # (Q, d)
     blocks: jax.Array,         # (B, BS, d)
     *,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
-    """Distances (NB, Q, BS): each unique page scored against ALL queries."""
+    """Distances (NB, BS, Q): each unique page scored against ALL queries
+    (page-major, queries along the lanes)."""
     nb = unique_blocks.shape[0]
     q_n, dim = queries.shape
     _, bs, _ = blocks.shape
@@ -110,13 +201,13 @@ def scan_batched(
             pl.BlockSpec((q_n, dim), lambda i, ids: (0, 0)),
             pl.BlockSpec((1, bs, dim), lambda i, ids: (ids[i], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, q_n, bs), lambda i, ids: (i, 0, 0)),
+        out_specs=pl.BlockSpec((1, bs, q_n), lambda i, ids: (i, 0, 0)),
     )
     return pl.pallas_call(
         _scan_batched_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nb, q_n, bs), jnp.float32),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((nb, bs, q_n), jnp.float32),
+        interpret=resolve_interpret(interpret),
     )(unique_blocks, queries, blocks)
 
 
@@ -124,40 +215,47 @@ def scan_batched(
 # Fused per-page top-k variants (streaming running-top-k reduce)
 # ---------------------------------------------------------------------------
 
-# Plain Python float: a jnp scalar would be a captured traced constant,
-# which pallas_call rejects (same trick as l2_topk).
-BIG = 3.0e38
-
-
-def _kmin_rows(d, *, k: int):
-    """Unrolled k-min per row of ``d (rows, cols)``: the l2_topk min/mask
-    loop.  Returns ``(dists (rows, k), argmins (rows, k))``."""
-    rows, cols = d.shape
-    col = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
-    ms, as_ = [], []
-    for _ in range(k):
-        m = jnp.min(d, axis=1)
-        a = jnp.argmin(d, axis=1).astype(jnp.int32)
-        ms.append(m)
-        as_.append(a)
-        d = jnp.where(col == a[:, None], BIG, d)
-    return jnp.stack(ms, axis=1), jnp.stack(as_, axis=1)
-
-
 def _scan_per_query_topk_kernel(
     table_ref, q_ref, blk_ref, bias_ref, out_d_ref, out_i_ref, *, k: int
 ):
-    # q_ref: (1, d); blk_ref: (1, BS, d); bias_ref: (1, 1, BS) f32 (0 live,
-    # +BIG dead); out: (1, 1, k) dists + slot indices within the page.
-    q = q_ref[0, :].astype(jnp.float32)
+    # q_ref: (1, 1, d); blk_ref: (1, BS, d); bias_ref: (1, 1, 1, BS) f32
+    # (0 live, +BIG dead); out: (1, 1, 1, k) dists + slot indices within
+    # the page.
+    q = q_ref[0].astype(jnp.float32)              # (1, d)
     b = blk_ref[0].astype(jnp.float32)            # (BS, d)
-    bsq = jnp.sum(b * b, axis=1)                  # (BS,)
-    cross = jnp.dot(b, q, preferred_element_type=jnp.float32)  # (BS,)
-    qsq = jnp.sum(q * q)
-    d = jnp.maximum(qsq - 2.0 * cross + bsq, 0.0) + bias_ref[0, 0, :]
-    kd, ki = _kmin_rows(d[None, :], k=k)          # (1, k)
-    out_d_ref[0] = kd
-    out_i_ref[0] = ki
+    d = _page_dists(q, b) + bias_ref[0, 0]        # (1, BS)
+    kd, ki = _kmin(d, k=k, axis=1)                # (1, k)
+    out_d_ref[0, 0] = kd
+    out_i_ref[0, 0] = ki
+
+
+def _scan_per_query_topk_rows(block_table, queries, blocks, slot_bias, *,
+                              k, interpret):
+    q_n, nb = block_table.shape
+    _, bs, dim = blocks.shape
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(q_n, nb),
+        in_specs=[
+            pl.BlockSpec((1, 1, dim), lambda q, j, table: (q, 0, 0)),
+            pl.BlockSpec((1, bs, dim), lambda q, j, table: (table[q, j], 0, 0)),
+            pl.BlockSpec((1, 1, 1, bs), lambda q, j, table: (q, j, 0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, 1, k), lambda q, j, table: (q, j, 0, 0)),
+            pl.BlockSpec((1, 1, 1, k), lambda q, j, table: (q, j, 0, 0)),
+        ],
+    )
+    out_d, out_i = pl.pallas_call(
+        functools.partial(_scan_per_query_topk_kernel, k=k),
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((q_n, nb, 1, k), jnp.float32),
+            jax.ShapeDtypeStruct((q_n, nb, 1, k), jnp.int32),
+        ],
+        interpret=resolve_interpret(interpret),
+    )(block_table, queries[:, None, :], blocks, slot_bias[:, :, None, :])
+    return out_d.reshape(q_n, nb, k), out_i.reshape(q_n, nb, k)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
@@ -168,57 +266,63 @@ def scan_per_query_topk(
     slot_bias: jax.Array,    # (Q, NB, BS) f32 — 0 live, +BIG dead
     *,
     k: int,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Per-query paged scan with fused per-page k-min.
 
     Returns ``(dists (Q, NB, k), slots (Q, NB, k))`` where ``slots`` index
     into the page (0..BS); dead candidates carry dist >= BIG."""
-    q_n, nb = block_table.shape
-    _, bs, dim = blocks.shape
-    assert k <= bs, (k, bs)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(q_n, nb),
-        in_specs=[
-            pl.BlockSpec((1, dim), lambda q, j, table: (q, 0)),
-            pl.BlockSpec((1, bs, dim), lambda q, j, table: (table[q, j], 0, 0)),
-            pl.BlockSpec((1, 1, bs), lambda q, j, table: (q, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, k), lambda q, j, table: (q, j, 0)),
-            pl.BlockSpec((1, 1, k), lambda q, j, table: (q, j, 0)),
-        ],
+    assert k <= blocks.shape[1], (k, blocks.shape)
+    return _by_query_chunks(
+        functools.partial(_scan_per_query_topk_rows, k=k, interpret=interpret),
+        block_table, queries, blocks, slot_bias,
     )
-    return pl.pallas_call(
-        functools.partial(_scan_per_query_topk_kernel, k=k),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((q_n, nb, k), jnp.float32),
-            jax.ShapeDtypeStruct((q_n, nb, k), jnp.int32),
-        ],
-        interpret=interpret,
-    )(block_table, queries, blocks, slot_bias)
 
 
 def _scan_per_query_topk_q8_kernel(
     table_ref, q_ref, blk_ref, bias_ref, sz_ref, out_d_ref, out_i_ref, *, k: int
 ):
-    # Dequant-fused variant: blk_ref holds int8 codes; sz_ref (1, 1, 2)
+    # Dequant-fused variant: blk_ref holds int8 codes; sz_ref (1, 1, 1, 2)
     # carries the page's posting [scale, zero], riding the block-table DMA
     # exactly like the liveness bias — the page streams at 1 byte/dim and
     # is reconstructed on the VPU before the distance math.
-    q = q_ref[0, :].astype(jnp.float32)
-    scale = sz_ref[0, 0, 0]
-    zero = sz_ref[0, 0, 1]
-    b = blk_ref[0].astype(jnp.float32) * scale + zero   # (BS, d) dequant
-    bsq = jnp.sum(b * b, axis=1)                  # (BS,)
-    cross = jnp.dot(b, q, preferred_element_type=jnp.float32)  # (BS,)
-    qsq = jnp.sum(q * q)
-    d = jnp.maximum(qsq - 2.0 * cross + bsq, 0.0) + bias_ref[0, 0, :]
-    kd, ki = _kmin_rows(d[None, :], k=k)          # (1, k)
-    out_d_ref[0] = kd
-    out_i_ref[0] = ki
+    q = q_ref[0].astype(jnp.float32)              # (1, d)
+    b = _dequant(blk_ref[0], sz_ref[0, 0])        # (BS, d)
+    d = _page_dists(q, b) + bias_ref[0, 0]        # (1, BS)
+    kd, ki = _kmin(d, k=k, axis=1)                # (1, k)
+    out_d_ref[0, 0] = kd
+    out_i_ref[0, 0] = ki
+
+
+def _scan_per_query_topk_q8_rows(block_table, queries, blocks, slot_bias,
+                                 page_sz, *, k, interpret):
+    q_n, nb = block_table.shape
+    _, bs, dim = blocks.shape
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(q_n, nb),
+        in_specs=[
+            pl.BlockSpec((1, 1, dim), lambda q, j, table: (q, 0, 0)),
+            pl.BlockSpec((1, bs, dim), lambda q, j, table: (table[q, j], 0, 0)),
+            pl.BlockSpec((1, 1, 1, bs), lambda q, j, table: (q, j, 0, 0)),
+            pl.BlockSpec((1, 1, 1, 2), lambda q, j, table: (q, j, 0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, 1, k), lambda q, j, table: (q, j, 0, 0)),
+            pl.BlockSpec((1, 1, 1, k), lambda q, j, table: (q, j, 0, 0)),
+        ],
+    )
+    out_d, out_i = pl.pallas_call(
+        functools.partial(_scan_per_query_topk_q8_kernel, k=k),
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((q_n, nb, 1, k), jnp.float32),
+            jax.ShapeDtypeStruct((q_n, nb, 1, k), jnp.int32),
+        ],
+        interpret=resolve_interpret(interpret),
+    )(block_table, queries[:, None, :], blocks, slot_bias[:, :, None, :],
+      page_sz[:, :, None, :])
+    return out_d.reshape(q_n, nb, k), out_i.reshape(q_n, nb, k)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
@@ -230,56 +334,30 @@ def scan_per_query_topk_q8(
     page_sz: jax.Array,      # (Q, NB, 2) f32 — per-page [scale, zero]
     *,
     k: int,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Per-query paged scan over int8 codes with in-kernel dequant.
 
     Same contract as `scan_per_query_topk`; distances are computed on the
     reconstructed ``code * scale + zero`` values."""
-    q_n, nb = block_table.shape
-    _, bs, dim = blocks.shape
-    assert k <= bs, (k, bs)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(q_n, nb),
-        in_specs=[
-            pl.BlockSpec((1, dim), lambda q, j, table: (q, 0)),
-            pl.BlockSpec((1, bs, dim), lambda q, j, table: (table[q, j], 0, 0)),
-            pl.BlockSpec((1, 1, bs), lambda q, j, table: (q, j, 0)),
-            pl.BlockSpec((1, 1, 2), lambda q, j, table: (q, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, k), lambda q, j, table: (q, j, 0)),
-            pl.BlockSpec((1, 1, k), lambda q, j, table: (q, j, 0)),
-        ],
+    assert k <= blocks.shape[1], (k, blocks.shape)
+    return _by_query_chunks(
+        functools.partial(
+            _scan_per_query_topk_q8_rows, k=k, interpret=interpret
+        ),
+        block_table, queries, blocks, slot_bias, page_sz,
     )
-    return pl.pallas_call(
-        functools.partial(_scan_per_query_topk_q8_kernel, k=k),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((q_n, nb, k), jnp.float32),
-            jax.ShapeDtypeStruct((q_n, nb, k), jnp.int32),
-        ],
-        interpret=interpret,
-    )(block_table, queries, blocks, slot_bias, page_sz)
 
 
 def _scan_batched_topk_kernel(
     ids_ref, q_ref, blk_ref, bias_ref, out_d_ref, out_i_ref, *, k: int
 ):
-    # q_ref: (Q, d) resident; blk_ref: (1, BS, d); bias_ref: (1, BS);
-    # out: (1, Q, k) dists + slot indices.
+    # q_ref: (Q, d) resident; blk_ref: (1, BS, d); bias_ref: (1, BS, 1);
+    # out: (1, k, Q) dists + slot indices, queries along the lanes.
     q = q_ref[...].astype(jnp.float32)            # (Q, d)
     b = blk_ref[0].astype(jnp.float32)            # (BS, d)
-    qsq = jnp.sum(q * q, axis=1, keepdims=True)   # (Q, 1)
-    bsq = jnp.sum(b * b, axis=1)                  # (BS,)
-    cross = jax.lax.dot_general(
-        q, b, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                             # (Q, BS)
-    d = jnp.maximum(qsq - 2.0 * cross + bsq[None, :], 0.0)
-    d = d + bias_ref[0, :][None, :]
-    kd, ki = _kmin_rows(d, k=k)                   # (Q, k)
+    d = _page_dists_t(q, b) + bias_ref[0]         # (BS, Q)
+    kd, ki = _kmin(d, k=k, axis=0)                # (k, Q)
     out_d_ref[0] = kd
     out_i_ref[0] = ki
 
@@ -292,11 +370,13 @@ def scan_batched_topk(
     slot_bias: jax.Array,      # (NB, BS) f32 — 0 live, +BIG dead
     *,
     k: int,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Batch-dedup paged scan with fused per-(page, query) k-min.
 
-    Returns ``(dists (NB, Q, k), slots (NB, Q, k))``."""
+    Returns ``(dists (NB, k, Q), slots (NB, k, Q))`` — page-major with
+    the queries along the lanes, the layout in which a page's Q·k
+    candidates are stored densely."""
     nb = unique_blocks.shape[0]
     q_n, dim = queries.shape
     _, bs, _ = blocks.shape
@@ -307,43 +387,34 @@ def scan_batched_topk(
         in_specs=[
             pl.BlockSpec((q_n, dim), lambda i, ids: (0, 0)),
             pl.BlockSpec((1, bs, dim), lambda i, ids: (ids[i], 0, 0)),
-            pl.BlockSpec((1, bs), lambda i, ids: (i, 0)),
+            pl.BlockSpec((1, bs, 1), lambda i, ids: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, q_n, k), lambda i, ids: (i, 0, 0)),
-            pl.BlockSpec((1, q_n, k), lambda i, ids: (i, 0, 0)),
+            pl.BlockSpec((1, k, q_n), lambda i, ids: (i, 0, 0)),
+            pl.BlockSpec((1, k, q_n), lambda i, ids: (i, 0, 0)),
         ],
     )
     return pl.pallas_call(
         functools.partial(_scan_batched_topk_kernel, k=k),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((nb, q_n, k), jnp.float32),
-            jax.ShapeDtypeStruct((nb, q_n, k), jnp.int32),
+            jax.ShapeDtypeStruct((nb, k, q_n), jnp.float32),
+            jax.ShapeDtypeStruct((nb, k, q_n), jnp.int32),
         ],
-        interpret=interpret,
-    )(unique_blocks, queries, blocks, slot_bias)
+        interpret=resolve_interpret(interpret),
+    )(unique_blocks, queries, blocks, slot_bias[:, :, None])
 
 
 def _scan_batched_topk_q8_kernel(
     ids_ref, q_ref, blk_ref, bias_ref, sz_ref, out_d_ref, out_i_ref, *, k: int
 ):
-    # Batched dequant-fused variant: sz_ref (1, 2) carries the unique
+    # Batched dequant-fused variant: sz_ref (1, 1, 2) carries the unique
     # page's [scale, zero] (one posting owns each block, so the page has a
     # single parameter pair no matter how many queries probe it).
     q = q_ref[...].astype(jnp.float32)            # (Q, d)
-    scale = sz_ref[0, 0]
-    zero = sz_ref[0, 1]
-    b = blk_ref[0].astype(jnp.float32) * scale + zero   # (BS, d) dequant
-    qsq = jnp.sum(q * q, axis=1, keepdims=True)   # (Q, 1)
-    bsq = jnp.sum(b * b, axis=1)                  # (BS,)
-    cross = jax.lax.dot_general(
-        q, b, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                             # (Q, BS)
-    d = jnp.maximum(qsq - 2.0 * cross + bsq[None, :], 0.0)
-    d = d + bias_ref[0, :][None, :]
-    kd, ki = _kmin_rows(d, k=k)                   # (Q, k)
+    b = _dequant(blk_ref[0], sz_ref[0])           # (BS, d)
+    d = _page_dists_t(q, b) + bias_ref[0]         # (BS, Q)
+    kd, ki = _kmin(d, k=k, axis=0)                # (k, Q)
     out_d_ref[0] = kd
     out_i_ref[0] = ki
 
@@ -357,7 +428,7 @@ def scan_batched_topk_q8(
     page_sz: jax.Array,        # (NB, 2) f32 — per-page [scale, zero]
     *,
     k: int,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Batch-dedup paged scan over int8 codes with in-kernel dequant.
 
@@ -372,20 +443,21 @@ def scan_batched_topk_q8(
         in_specs=[
             pl.BlockSpec((q_n, dim), lambda i, ids: (0, 0)),
             pl.BlockSpec((1, bs, dim), lambda i, ids: (ids[i], 0, 0)),
-            pl.BlockSpec((1, bs), lambda i, ids: (i, 0)),
-            pl.BlockSpec((1, 2), lambda i, ids: (i, 0)),
+            pl.BlockSpec((1, bs, 1), lambda i, ids: (i, 0, 0)),
+            pl.BlockSpec((1, 1, 2), lambda i, ids: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, q_n, k), lambda i, ids: (i, 0, 0)),
-            pl.BlockSpec((1, q_n, k), lambda i, ids: (i, 0, 0)),
+            pl.BlockSpec((1, k, q_n), lambda i, ids: (i, 0, 0)),
+            pl.BlockSpec((1, k, q_n), lambda i, ids: (i, 0, 0)),
         ],
     )
     return pl.pallas_call(
         functools.partial(_scan_batched_topk_q8_kernel, k=k),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((nb, q_n, k), jnp.float32),
-            jax.ShapeDtypeStruct((nb, q_n, k), jnp.int32),
+            jax.ShapeDtypeStruct((nb, k, q_n), jnp.float32),
+            jax.ShapeDtypeStruct((nb, k, q_n), jnp.int32),
         ],
-        interpret=interpret,
-    )(unique_blocks, queries, blocks, slot_bias, page_sz)
+        interpret=resolve_interpret(interpret),
+    )(unique_blocks, queries, blocks, slot_bias[:, :, None],
+      page_sz[:, None, :])

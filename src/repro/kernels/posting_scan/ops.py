@@ -23,7 +23,7 @@ def scan_posting_blocks(
     pids: jax.Array,          # (Q, nprobe) probed postings (-1 = none)
     blocks: jax.Array,        # (B, BS, d)
     *,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Per-query paged scan.  Returns ``(dists (Q, nprobe*MB*BS), flat_slot
     (Q, nprobe*MB*BS) bool valid-page mask)`` — caller applies vid/version
@@ -47,9 +47,9 @@ def scan_unique_blocks(
     unique_blocks: jax.Array,  # (NB,) i32, -1 = padding
     blocks: jax.Array,       # (B, BS, d)
     *,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
-    """Batch-dedup scan.  Returns dists (NB, Q, BS) with padded pages = BIG."""
+    """Batch-dedup scan.  Returns dists (NB, BS, Q) with padded pages = BIG."""
     ok = unique_blocks >= 0
     d = K.scan_batched(
         jnp.maximum(unique_blocks, 0), queries, blocks, interpret=interpret
@@ -69,7 +69,7 @@ def scan_posting_blocks_topk(
     blocks: jax.Array,       # (B, BS, d)
     *,
     k: int,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Per-query paged scan with fused per-page k-min.
 
@@ -92,11 +92,11 @@ def scan_unique_blocks_topk(
     blocks: jax.Array,        # (B, BS, d)
     *,
     k: int,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Batch-dedup paged scan with fused per-(page, query) k-min.
 
-    Returns ``(dists (NB, Q, k), slots (NB, Q, k))``."""
+    Returns ``(dists (NB, k, Q), slots (NB, k, Q))``."""
     bias = jnp.where(
         slot_live & (unique_blocks >= 0)[:, None], jnp.float32(0), BIG
     )
@@ -116,7 +116,7 @@ def scan_posting_blocks_topk_q8(
     page_zero: jax.Array,    # (Q, NB) f32 — per-page posting zero-point
     *,
     k: int,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """`scan_posting_blocks_topk` over int8 codes: the per-page scale/zero
     ride the DMA and the page is dequantized inside the kernel."""
@@ -143,7 +143,7 @@ def scan_unique_blocks_topk_q8(
     page_zero: jax.Array,     # (NB,) f32 — per-unique-page zero-point
     *,
     k: int,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """`scan_unique_blocks_topk` over int8 codes with in-kernel dequant."""
     bias = jnp.where(

@@ -18,10 +18,10 @@ def scan_posting_blocks_ref(
 def scan_unique_blocks_ref(
     unique_blocks: jax.Array, queries: jax.Array, blocks: jax.Array
 ) -> jax.Array:
-    """(NB, Q, BS) distances — batched unique-page scan."""
+    """(NB, BS, Q) distances — batched unique-page scan, page-major."""
     gathered = blocks[unique_blocks].astype(jnp.float32)  # (NB, BS, d)
     q = queries.astype(jnp.float32)
-    diff = gathered[:, None, :, :] - q[None, :, None, :]
+    diff = gathered[:, :, None, :] - q[None, None, :, :]
     return jnp.sum(diff * diff, axis=-1)
 
 
@@ -30,6 +30,13 @@ def _kmin_ref(d: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
     (matches the kernels' min/mask loop)."""
     neg, idx = jax.lax.top_k(-d, k)
     return -neg, idx.astype(jnp.int32)
+
+
+def _kmin_pages_ref(d: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
+    """`_kmin_ref` over the slot axis of page-major ``d (NB, BS, Q)`` →
+    ``(NB, k, Q)``."""
+    kd, ki = _kmin_ref(jnp.swapaxes(d, 1, 2), k)
+    return jnp.swapaxes(kd, 1, 2), jnp.swapaxes(ki, 1, 2)
 
 
 def scan_per_query_topk_ref(
@@ -45,10 +52,10 @@ def scan_batched_topk_ref(
     unique_blocks: jax.Array, queries: jax.Array, blocks: jax.Array,
     slot_bias: jax.Array, k: int,
 ) -> tuple[jax.Array, jax.Array]:
-    """(NB, Q, k) per-(page, query) k-min candidates — batched schedule."""
+    """(NB, k, Q) per-(page, query) k-min candidates — batched schedule."""
     d = scan_unique_blocks_ref(unique_blocks, queries, blocks)
-    d = d + slot_bias[:, None, :]
-    return _kmin_ref(d, k)
+    d = d + slot_bias[:, :, None]
+    return _kmin_pages_ref(d, k)
 
 
 def scan_per_query_topk_q8_ref(
@@ -73,6 +80,6 @@ def scan_batched_topk_q8_ref(
     g = blocks[unique_blocks].astype(jnp.float32)         # (NB, BS, d)
     g = g * page_sz[:, 0][:, None, None] + page_sz[:, 1][:, None, None]
     q = queries.astype(jnp.float32)
-    diff = g[:, None, :, :] - q[None, :, None, :]
-    d = jnp.sum(diff * diff, axis=-1) + slot_bias[:, None, :]
-    return _kmin_ref(d, k)
+    diff = g[:, :, None, :] - q[None, None, :, :]
+    d = jnp.sum(diff * diff, axis=-1) + slot_bias[:, :, None]
+    return _kmin_pages_ref(d, k)

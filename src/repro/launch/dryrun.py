@@ -40,7 +40,6 @@ def run_cell(arch: str, shape: str, mesh_kind: str, out_dir: str) -> dict:
     import jax
 
     from repro.configs import get_cell
-    from repro.distributed.sharding import mesh_context
     from repro.launch.mesh import make_production_mesh
     from repro.launch.roofline import collective_bytes, model_flops, roofline_terms
 
@@ -73,7 +72,7 @@ def run_cell(arch: str, shape: str, mesh_kind: str, out_dir: str) -> dict:
             is_leaf=lambda x: isinstance(x, PartitionSpec),
         )
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         if cell.make_mesh_step is not None:
             step, args = cell.make_mesh_step(mesh, multi_pod)
             lowered = step.lower(*args)
@@ -168,7 +167,7 @@ def run_cell(arch: str, shape: str, mesh_kind: str, out_dir: str) -> dict:
             vkwargs = {}
             if vouts is not None:
                 vkwargs["out_shardings"] = to_shardings(vouts(multi_pod))
-            with mesh_context(mesh):
+            with jax.set_mesh(mesh):
                 vlow = jax.jit(
                     vstep, in_shardings=to_shardings(vshard(multi_pod)),
                     **vkwargs,

@@ -210,17 +210,22 @@ def main() -> None:
 
     if args.shards > 1:
         # a replicated sharded service lives on a (data=replicas,
-        # model=shards) mesh — one fake device per index copy per shard
+        # model=shards) mesh — one device per index copy per shard.  The
+        # flag only sizes the host (CPU) platform: on an accelerator the
+        # mesh takes its real devices, and without one JAX falls back to
+        # these fake host devices.
         n_dev = args.shards * max(args.replicas, 1)
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={n_dev} "
             + os.environ.get("XLA_FLAGS", "")
         )
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
     if args.recover and not args.durable:
         raise SystemExit("--recover needs --durable DIR")
 
     import spfresh
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from repro.data import UpdateWorkload
 
     spec = build_spec(args)

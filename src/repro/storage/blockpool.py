@@ -417,12 +417,17 @@ def gather_posting(
     bids = pool.posting_blocks[pid]  # (MB,)
     safe = jnp.maximum(bids, 0)
     payload = pool.blocks_exact if pool.blocks_exact is not None else pool.blocks
-    vecs = payload[safe]             # (MB, BS, d)
     vids = pool.block_vid[safe]
     vers = pool.block_ver[safe]
     cap = pool.posting_capacity
     d = pool.dim
-    vecs = vecs.reshape(cap, d)
+    # gathered as rows of the flat (B·BS, d) pool, not as (MB, BS, d)
+    # pages: the TPU compiler fuses page-shaped and posting-shaped
+    # converts of one int8 page gather and then aborts (fusion_util
+    # TransformWindow)
+    bs = pool.block_size
+    rows = (safe[:, None] * bs + jnp.arange(bs, dtype=jnp.int32)).reshape(cap)
+    vecs = payload.reshape(-1, d)[rows]
     vids = vids.reshape(cap)
     vers = vers.reshape(cap)
     idx = jnp.arange(cap, dtype=jnp.int32)

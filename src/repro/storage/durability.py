@@ -181,13 +181,15 @@ REPLAY_CRITICAL_FIELDS = (
     # never stamped them) still pass.
     "codec", "rerank_factor",
     # Insert/reassign ROUTING runs through `lire.navigate`, whose kernel
-    # path (Pallas nav vs XLA oracle, compiled vs interpret) these two
-    # select.  The paths are numerically equivalent only up to top-k
-    # tie-breaking on equal distances — enough to route a vector to a
-    # different posting on replay — so they must match the snapshot.
-    # Stamped by name: snapshots from before this stamp never recorded
-    # them and still pass.
-    "use_pallas_nav", "pallas_interpret",
+    # path (Pallas nav vs XLA oracle) this selects.  The paths are
+    # numerically equivalent only up to top-k tie-breaking on equal
+    # distances — enough to route a vector to a different posting on
+    # replay — so it must match the snapshot.  Stamped by name:
+    # snapshots from before this stamp never recorded it and still pass.
+    # Whether a kernel runs compiled or interpreted is the platform's
+    # choice (repro.kernels.backend), not config: a snapshot's stale
+    # "pallas_interpret" stamp is ignored.
+    "use_pallas_nav",
 )
 
 # Serving-side fields a reopened index may change freely: they only

@@ -149,12 +149,19 @@ def _assemble(template: T, leaves_np: list[np.ndarray]) -> T:
     tmpl_leaves, treedef = jax.tree_util.tree_flatten(template)
     out = []
     for arr, tmpl in zip(leaves_np, tmpl_leaves):
-        want = np.asarray(tmpl)
-        if arr.shape != want.shape:
+        # shape/dtype only: the template may be abstract (ShapeDtypeStruct)
+        # or live on a device, and is never copied to the host
+        shape, dtype = np.shape(tmpl), np.result_type(tmpl)
+        if arr.shape != shape:
             raise ValueError(
-                f"snapshot leaf shape {arr.shape} != template {want.shape}"
+                f"snapshot leaf shape {arr.shape} != template {shape}"
             )
-        out.append(jax.numpy.asarray(arr, dtype=want.dtype))
+        # an abstract template (the stacked sharded state's) keeps the
+        # leaves on the host: the owner places one shard per device, and
+        # no single device could hold the whole stack
+        abstract = isinstance(tmpl, jax.ShapeDtypeStruct)
+        out.append(np.asarray(arr, dtype) if abstract
+                   else jax.numpy.asarray(arr, dtype=dtype))
     return treedef.unflatten(out)
 
 

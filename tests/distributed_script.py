@@ -126,7 +126,7 @@ with MESH:
     recall8 = hits8 / (len(queries) * 10)
     assert recall8 > 0.85, f"8-shard recall {recall8}"
     stacked8, h8 = insert8(stacked8, jnp.asarray(new), jnp.ones(len(new), bool))
-    assert (np.asarray(h8) >= 0).all()
+    assert (np.asarray(h8) >= 0).all(), np.asarray(h8)
     print(f"PASS document_sharded_8 recall={recall8:.3f}")
 
 # ---- elastic re-shard 4 -> 2 ----

@@ -66,6 +66,13 @@ np.testing.assert_allclose(d_l[:, 0], d_s[:, 0], rtol=1e-4)  # same corpus
 local.close()
 print("PASS one_spec_two_backends")
 
+# ---- the built state is placed one shard per device, not on device 0 ----
+for leaf in jax.tree_util.tree_leaves(svc.backend.stacked):
+    owners = {(sh.index[0].start or 0): sh.device
+              for sh in leaf.addressable_shards}
+    assert sorted(owners) == [0, 1] and len(set(owners.values())) == 2, owners
+print("PASS shard_per_device")
+
 # ---- stream updates through the pipeline (no checkpoint) ----
 new = make_clustered(rng, 90, 16, n_clusters=3)
 handles = []

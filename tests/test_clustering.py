@@ -6,7 +6,9 @@ from repro.core.clustering import (
     balanced_kmeans,
     balanced_two_means,
     hierarchical_balanced_kmeans,
+    pow2_bucket,
 )
+from repro.utils.compile_cache import CompileCounter
 from tests.conftest import make_clustered
 
 
@@ -69,3 +71,21 @@ def test_hierarchical_build_degenerate_identical_points():
     cen, assign = hierarchical_balanced_kmeans(x, max_posting_size=16)
     counts = np.bincount(assign, minlength=cen.shape[0])
     assert counts.sum() == 100
+
+
+def test_pow2_bucket():
+    assert [pow2_bucket(n) for n in (1, 64, 65, 1000, 1024, 1025)] == [
+        64, 64, 128, 1024, 1024, 2048]
+
+
+def test_hierarchical_build_compiles_per_bucket_not_per_node(rng):
+    """Nodes are padded to power-of-two buckets, so a build compiles
+    about one program per (bucket, k) pair — not one per node size (a
+    20k-row build has hundreds of distinct node sizes)."""
+    x = make_clustered(rng, 20000, 16, n_clusters=64, spread=0.3)
+    jax.clear_caches()
+    with CompileCounter() as cc:
+        cen, assign = hierarchical_balanced_kmeans(x, max_posting_size=40)
+    assert np.bincount(assign).max() <= 40
+    assert cen.shape[0] >= 20000 // 40
+    assert cc.compiles <= 40, cc.compiles

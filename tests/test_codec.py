@@ -396,6 +396,23 @@ def test_replay_rejects_codec_drift():
     check_replay_config(legacy, cfg)
 
 
+def test_replay_ignores_the_platform_interpret_stamp():
+    """Snapshots once stamped whether the kernels ran interpreted; that is
+    the platform's choice now, so a snapshot written on the CPU reopens
+    on a TPU — while a real geometry drift is still refused."""
+    import dataclasses
+
+    from repro.storage.durability import check_replay_config
+
+    cfg = _tiny_cfg()
+    stamped = dataclasses.asdict(cfg)
+    stamped["pallas_interpret"] = True
+    check_replay_config({"extra": {"lire_config": stamped}}, cfg)
+    stamped["dim"] = cfg.dim + 1
+    with pytest.raises(ValueError, match="dim"):
+        check_replay_config({"extra": {"lire_config": stamped}}, cfg)
+
+
 # ---------------------------------------------------------------------------
 # Recall-floor gate: int8 + exact rerank within 0.01 recall@10 of fp32
 # ---------------------------------------------------------------------------

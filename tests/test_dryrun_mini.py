@@ -18,7 +18,6 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_cell
-from repro.distributed.sharding import mesh_context
 from repro.launch.roofline import collective_bytes, roofline_terms
 
 mesh = jax.make_mesh((2, 4), ("data", "model"))
@@ -36,7 +35,7 @@ def fix(tree):
         conv, tree, is_leaf=lambda x: isinstance(x, P)
     )
 
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     lowered = jax.jit(cell.step_fn, in_shardings=fix(specs)).lower(*args)
     compiled = lowered.compile()
 ca = compiled.cost_analysis()

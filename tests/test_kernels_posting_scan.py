@@ -226,5 +226,5 @@ def test_scan_consistency_between_variants(rng):
         for j, b in enumerate(np.asarray(table)[q]):
             bi = int(np.where(uniq_np == b)[0][0])
             np.testing.assert_allclose(
-                per_q[q, j], batched[bi, q], rtol=1e-5, atol=1e-5
+                per_q[q, j], batched[bi, :, q], rtol=1e-5, atol=1e-5
             )

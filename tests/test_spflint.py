@@ -200,14 +200,14 @@ def test_vmem_estimate_matches_actual_scan_batched_topk_shapes():
     # the real per-grid-step blocks, from the wrapper's BlockSpecs
     rng = np.random.default_rng(0)
     queries = rng.standard_normal((q_n, dim)).astype(np.float32)
-    blocks = rng.standard_normal((8, bs, dim)).astype(np.float32)
+    blocks = rng.integers(-127, 128, (8, bs, dim)).astype(np.int8)
     slot_bias = np.zeros((8, bs), np.float32)
     expect = [
         ("in", (q_n, dim), queries.itemsize),         # resident queries
         ("in", (1, bs, dim), blocks.itemsize),        # one streamed page
-        ("in", (1, bs), slot_bias.itemsize),          # liveness bias row
-        ("out", (1, q_n, k), np.dtype(np.float32).itemsize),
-        ("out", (1, q_n, k), np.dtype(np.int32).itemsize),
+        ("in", (1, bs, 1), slot_bias.itemsize),       # liveness bias column
+        ("out", (1, k, q_n), np.dtype(np.float32).itemsize),
+        ("out", (1, k, q_n), np.dtype(np.int32).itemsize),
     ]
     got = [(o["role"], tuple(o["shape"])) for o in row["operands"]]
     assert got == [(r, s) for r, s, _ in expect]
@@ -222,7 +222,7 @@ def test_vmem_estimate_matches_actual_scan_batched_topk_shapes():
         jnp.asarray(blocks), jnp.asarray(slot_bias),
         k=k, interpret=True,
     )
-    assert kd.shape == (8, q_n, k) and ki.shape == (8, q_n, k)
+    assert kd.shape == (8, k, q_n) and ki.shape == (8, k, q_n)
     assert bool(jnp.isfinite(kd).all()) and int(ki.max()) < bs
 
 
