@@ -130,11 +130,15 @@ def run_json(quick: bool = True) -> dict:
     model = _traffic_model(state, queries, nprobe)
     page_bytes = model["page_bytes"]
 
-    # batched-schedule page accounting (overflow > 0 = budget dropped pages)
-    pstats = {
-        kk: int(v) for kk, v in
-        lire.scan_page_stats(state, queries, nprobe=nprobe).items()
-    }
+    # batched-schedule page accounting (dropped > 0 = budget dropped pages),
+    # the counts the search dispatch itself returns
+    *_, access = lire.search(
+        state, queries, k=k, nprobe=nprobe, use_pallas_scan=True,
+        scan_schedule="batched", with_access=True,
+    )
+    _, pages = lire.split_access(np.asarray(access))
+    pstats = dict(zip(("pages_unique", "pages_dropped", "pages_grid"),
+                      (int(x) for x in pages)))
 
     out = {
         "workload": {

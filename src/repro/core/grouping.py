@@ -152,8 +152,9 @@ def search_grouped(
         state, gidx, queries, nprobe=nprobe, gprobe=gprobe
     )
     probe_valid = nav_d < MASK_DISTANCE / 2
-    return lire.scan_and_reduce(
+    d, v, _ = lire.scan_and_reduce(
         state, queries, pids, probe_valid,
         k=k, probe_chunk=probe_chunk,
         use_pallas_scan=use_pallas_scan, scan_schedule=scan_schedule,
     )
+    return d, v

@@ -211,6 +211,14 @@ def build_state(
 # pool in place instead of copying it every batch.
 # ---------------------------------------------------------------------------
 
+def _owned(x: np.ndarray) -> jax.Array:
+    """A host batch as a device array over memory of its own.  On the CPU
+    ``jnp.asarray`` aliases the host buffer, which an asynchronous
+    dispatch reads after it returns; the copy (a few KB) keeps a refilled
+    staging buffer out of a dispatch still in flight."""
+    return jnp.asarray(np.array(x))
+
+
 @functools.lru_cache(maxsize=None)
 def search_step(
     k: int,
@@ -225,8 +233,10 @@ def search_step(
     ``probe_chunk`` / ``use_pallas_scan`` / ``scan_schedule`` select the
     posting-scan data path (None defers to the state's config flags) —
     the serving pipeline threads them through from ``EngineConfig``.
-    ``with_access`` adds the per-posting probe histogram as a third
-    output (the serving backend's access-telemetry source).
+    ``with_access`` adds a third output, the per-posting probe histogram
+    with the dispatch's page counts appended (``lire.split_access``
+    parts them): the serving backend's access telemetry and ``scan.*``
+    counters.
     """
     return jax.jit(
         functools.partial(
@@ -422,7 +432,9 @@ class SPFreshIndex:
 
     # ------------------- Batched pipeline entry points -----------------
     # Fixed-shape, one-dispatch variants driven by the ServeEngine; the
-    # caller (the RequestQueue) owns padding and bucket discipline.
+    # caller (the RequestQueue) owns padding and bucket discipline, and
+    # refills its staging buffers as soon as a dispatch returns, so every
+    # batch argument goes in as an ``_owned`` copy.
 
     def search_padded(
         self, queries: np.ndarray, k: int, *, nprobe: int | None = None,
@@ -440,10 +452,10 @@ class SPFreshIndex:
             with_access,
         )
         if qvalid is None:
-            out = step(self.state, jnp.asarray(queries))
+            out = step(self.state, _owned(queries))
         else:
             out = step(
-                self.state, jnp.asarray(queries),
+                self.state, _owned(queries),
                 qvalid=jnp.asarray(qvalid, bool),
             )
         if as_jax:
@@ -455,14 +467,13 @@ class SPFreshIndex:
     ) -> np.ndarray:
         """One donated-state insert dispatch; returns the landed mask."""
         self.state, landed = insert_step()(
-            self.state, jnp.asarray(vecs), jnp.asarray(vids),
-            jnp.asarray(valid),
+            self.state, _owned(vecs), _owned(vids), _owned(valid),
         )
         return np.asarray(landed)
 
     def delete_padded(self, vids: np.ndarray, valid: np.ndarray) -> None:
         self.state = delete_step()(
-            self.state, jnp.asarray(vids), jnp.asarray(valid)
+            self.state, _owned(vids), _owned(valid)
         )
 
     def maintain_round(

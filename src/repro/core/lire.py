@@ -283,30 +283,64 @@ def _page_slot_live(state: IndexState, pages: Array) -> tuple[Array, Array]:
     ``(..., BS)``.  The metadata gather is tiny (5 B/slot vs the d·dtype
     payload the Pallas kernel streams page-by-page)."""
     pool = state.pool
-    safe = jnp.maximum(pages, 0)
-    pvids = pool.block_vid[safe]
-    pvers = pool.block_ver[safe]
-    live = (
-        (pages >= 0)[..., None]
-        & (pvids >= 0)
-        & ~vm.is_stale(state.versions, pvids, pvers)
-    )
+    with jax.named_scope("liveness"):
+        safe = jnp.maximum(pages, 0)
+        pvids = pool.block_vid[safe]
+        pvers = pool.block_ver[safe]
+        live = (
+            (pages >= 0)[..., None]
+            & (pvids >= 0)
+            & ~vm.is_stale(state.versions, pvids, pvers)
+        )
     return pvids, live
+
+
+N_PAGE_COUNTS = 3
+
+
+def _page_counts(
+    flat: Array, counted: Array, member_pos: Array | None = None,
+    budget: int = 0,
+) -> Array:
+    """One dispatch's page accounting, int32 ``(pages scanned, pages
+    dropped, grid)``, over the probes ``counted (Q, nprobe)`` marks (the
+    search leaves padding rows out).  ``flat`` is the ``(Q, NB)`` page
+    table.  Without ``member_pos`` (a per-query scan) every counted page
+    is scanned and the grid is the table; with the batched schedule's
+    ``member_pos`` the distinct counted pages kept in its ``budget``-page
+    grid, and the counted pages it dropped, once per probing query."""
+    mb = flat.shape[1] // counted.shape[1]
+    real = (flat >= 0) & jnp.repeat(counted, mb, axis=1)
+    if member_pos is None:
+        return jnp.stack([jnp.sum(real), 0, flat.size]).astype(jnp.int32)
+    real = real.reshape(-1)
+    tgt = jnp.where(real & (member_pos >= 0), member_pos, budget)
+    kept = jnp.zeros((budget,), bool).at[tgt].set(True, mode="drop")
+    dropped = jnp.sum(real & (member_pos < 0))
+    return jnp.stack([jnp.sum(kept), dropped, budget]).astype(jnp.int32)
+
+
+def split_access(access):
+    """``search(..., with_access=True)``'s third output → ``(probe
+    histogram (num_postings_cap,), page counts (3,))``."""
+    return access[:-N_PAGE_COUNTS], access[-N_PAGE_COUNTS:]
 
 
 def _pallas_scan_candidates(
     state: IndexState, queries: Array, pids: Array, probe_valid: Array,
-    *, k: int, schedule: str,
-) -> tuple[Array, Array, Array, Array]:
+    counted: Array, *, k: int, schedule: str,
+) -> tuple[Array, Array, Array, Array, Array]:
     """Paged Pallas posting scan → reduced candidate set.
 
     Streams SSD-block-sized pages through the ``posting_scan`` kernels and
     keeps only the per-page ``min(k, BS)`` nearest live candidates, so
     neither the (Q, nprobe·cap, d) gather buffer nor the (Q, nprobe·MB·BS)
     distance matrix ever exists in HBM.  Returns ``(dists (Q, n),
-    vids (Q, n), pos (Q, n), live (Q, n))`` with n = pages·kpage; ``pos``
-    is each candidate's pool position (``block_id·BS + slot``, -1 dead),
-    which the exact rerank gathers from the cold tier.
+    vids (Q, n), pos (Q, n), live (Q, n), pages (3,))`` with n =
+    pages·kpage; ``pos`` is each candidate's pool position
+    (``block_id·BS + slot``, -1 dead), which the exact rerank gathers
+    from the cold tier; ``pages`` is the dispatch's ``_page_counts`` over
+    the probes ``counted`` marks.
 
     With the ``int8`` codec the dequant-fused kernel variants run instead:
     the probed posting's scale/zero ride the block-table DMA and the page
@@ -351,11 +385,13 @@ def _pallas_scan_candidates(
         cand_d = d.reshape(q, -1)
         cand_v = cand_v.reshape(q, -1)
         cand_p = cand_p.reshape(q, -1)
+        pages = _page_counts(flat, counted)
     elif schedule == "batched":
         budget = cfg.scan_page_budget or min(q * nprobe * mb, cfg.num_blocks)
         uniq, member_pos, _, _ = scan_ops.dedup_pages(
             flat.reshape(-1), budget=budget, num_blocks=cfg.num_blocks
         )
+        pages = _page_counts(flat, counted, member_pos, budget)
         pvids, live = _page_slot_live(state, uniq)      # (budget, BS)
         if quant:
             # invert the dedup: every original probe scatters its posting's
@@ -399,46 +435,7 @@ def _pallas_scan_candidates(
         raise ValueError(
             f"scan_schedule must be 'per_query' or 'batched', got {schedule!r}"
         )
-    return cand_d, cand_v, cand_p, cand_d < MASK_DISTANCE / 2
-
-
-@functools.partial(jax.jit, static_argnames=("nprobe", "scan_page_budget"))
-def scan_page_stats(
-    state: IndexState,
-    queries: Array,
-    *,
-    nprobe: int | None = None,
-    scan_page_budget: int | None = None,
-) -> dict[str, Array]:
-    """Batched-schedule page accounting for a query micro-batch.
-
-    The search hot path cannot surface the dedup counters (it returns only
-    ``(dists, vids)``), so overflow accounting lives here: run it on a
-    representative micro-batch to size ``scan_page_budget`` and to watch
-    for silent recall loss (``overflow > 0`` means the budget dropped
-    probed pages).  ``benchmarks/run.py --json`` reports it per workload.
-
-    Returns ``{"n_pages", "n_unique", "overflow"}`` (device scalars).
-    """
-    cfg = state.cfg
-    nprobe = cfg.nprobe if nprobe is None else nprobe
-    budget = scan_page_budget if scan_page_budget is not None \
-        else cfg.scan_page_budget
-    budget = budget or min(
-        queries.shape[0] * nprobe * cfg.max_blocks_per_posting,
-        cfg.num_blocks,
-    )
-    nav_d, pids = navigate(state, queries, nprobe)
-    probe_valid = nav_d < MASK_DISTANCE / 2
-    flat = _page_table(state, pids, probe_valid)
-    _, _, n_unique, overflow = scan_ops.dedup_pages(
-        flat.reshape(-1), budget=budget, num_blocks=cfg.num_blocks
-    )
-    return {
-        "n_pages": jnp.sum(flat >= 0),
-        "n_unique": n_unique,
-        "overflow": overflow,
-    }
+    return cand_d, cand_v, cand_p, cand_d < MASK_DISTANCE / 2, pages
 
 
 def _posting_positions(pool, flat_pids: Array) -> Array:
@@ -469,8 +466,9 @@ def _scan_probe_chunk(
     flat_pids = jnp.maximum(pids.reshape(-1), 0)
     vecs, vids, vers, slot_valid = bp.parallel_get_hot(state.pool, flat_pids)
     pos = _posting_positions(state.pool, flat_pids)
-    stale = vm.is_stale(state.versions, vids, vers)
-    live = slot_valid & ~stale & probe_valid.reshape(-1)[:, None]
+    with jax.named_scope("liveness"):
+        stale = vm.is_stale(state.versions, vids, vers)
+        live = slot_valid & ~stale & probe_valid.reshape(-1)[:, None]
     vecs = vecs.reshape(q, c * cap, -1)
     vids = vids.reshape(q, c * cap)
     pos = pos.reshape(q, c * cap)
@@ -527,7 +525,8 @@ def scan_and_reduce(
     probe_chunk: int = 0,
     use_pallas_scan: bool | None = None,
     scan_schedule: str | None = None,
-) -> tuple[Array, Array]:
+    counted: Array | None = None,
+) -> tuple[Array, Array, Array]:
     """Posting scan + dedup top-k over an already-navigated probe set.
 
     Shared by ``search`` and the grouped two-level search; the scan data
@@ -549,6 +548,13 @@ def scan_and_reduce(
     quantized scan, then rerank them against the cold exact-fp32 tier
     before the final top-k (the two-tier search closing the accuracy
     gap).
+
+    Returns ``(dists (Q, k), vids (Q, k), pages (3,))``: ``pages`` is the
+    dispatch's page accounting (``_page_counts``; the XLA paths count
+    every probed page of the grid as scanned) over the probes ``counted
+    (Q, nprobe)`` marks, by default ``probe_valid``.  Device operations carry
+    the named scopes ``scan`` (with ``liveness``, the version-map gather
+    and bias, inside it) and ``reduce``.
     """
     cfg = state.cfg
     q, nprobe = pids.shape
@@ -557,8 +563,10 @@ def scan_and_reduce(
     schedule = scan_schedule if scan_schedule is not None else cfg.scan_schedule
     rerank = cfg.rerank_factor > 1 and state.pool.blocks_exact is not None
     kq = k * cfg.rerank_factor if rerank else k
+    counted = probe_valid if counted is None else counted
 
-    def reduce_and_rerank(cand_d, cand_v, cand_p, live):
+    @jax.named_scope("reduce")
+    def reduce_and_rerank(cand_d, cand_v, cand_p, live, pages):
         n = cand_d.shape[1]
         kk = min(kq, n) if rerank else k
         m = _dedup_prefilter(cfg, kk, n)
@@ -566,22 +574,27 @@ def scan_and_reduce(
             lambda dd, vv, mm: _dedup_topk_1d_full(dd, vv, mm, kk, m)
         )(cand_d, cand_v, live)
         if not rerank:
-            return d, v
+            return d, v, pages
         pos = jnp.take_along_axis(cand_p, jnp.maximum(oi, 0), axis=1)
         pos = jnp.where(oi >= 0, pos, -1)
-        return _rerank_exact(state, queries, d, v, pos, k)
+        return (*_rerank_exact(state, queries, d, v, pos, k), pages)
 
     if pallas:
-        cand_d, cand_v, cand_p, live = _pallas_scan_candidates(
-            state, queries, pids, probe_valid, k=kq, schedule=schedule
-        )
-        return reduce_and_rerank(cand_d, cand_v, cand_p, live)
+        with jax.named_scope("scan"):
+            cand = _pallas_scan_candidates(
+                state, queries, pids, probe_valid, counted,
+                k=kq, schedule=schedule,
+            )
+        return reduce_and_rerank(*cand)
 
+    with jax.named_scope("scan"):
+        pages = _page_counts(_page_table(state, pids, probe_valid), counted)
     if probe_chunk <= 0 or nprobe % probe_chunk != 0 or nprobe == probe_chunk:
-        dists, vids, pos, live = _scan_probe_chunk(
-            state, queries, pids, probe_valid
-        )
-        return reduce_and_rerank(dists, vids, pos, live)
+        with jax.named_scope("scan"):
+            dists, vids, pos, live = _scan_probe_chunk(
+                state, queries, pids, probe_valid
+            )
+        return reduce_and_rerank(dists, vids, pos, live, pages)
 
     nc = nprobe // probe_chunk
     keep = min(max(4 * kq, 64), probe_chunk * cap)
@@ -608,9 +621,11 @@ def scan_and_reduce(
         jnp.full((q, keep), -1, jnp.int32),
         jnp.full((q, keep), -1, jnp.int32),
     )
-    (best_d, best_v, best_p), _ = jax.lax.scan(body, init, (pids_c, pvalid_c))
+    with jax.named_scope("scan"):
+        (best_d, best_v, best_p), _ = jax.lax.scan(
+            body, init, (pids_c, pvalid_c))
     live = best_d < MASK_DISTANCE / 2
-    return reduce_and_rerank(best_d, best_v, best_p, live)
+    return reduce_and_rerank(best_d, best_v, best_p, live, pages)
 
 
 @functools.partial(
@@ -644,25 +659,31 @@ def search(
     config flags.  See ``scan_and_reduce`` for the probe_chunk semantics
     of the oracle path.
 
-    ``with_access=True`` additionally returns the per-posting probe
-    histogram (``probe_histogram``) as a third output; the ``(dists,
-    vids)`` numerics are untouched.  ``qvalid (Q,)`` masks padded query
-    rows out of the histogram ONLY (their dists/vids rows are computed
-    regardless and discarded by the caller, as before).
+    ``with_access=True`` additionally returns, as a third output, the
+    per-posting probe histogram (``probe_histogram``) with the dispatch's
+    page counts appended, ``(pages scanned, pages dropped by the budget,
+    grid)`` (``scan_and_reduce``; ``split_access`` parts them): one
+    readback carries both.  The ``(dists, vids)`` numerics are
+    untouched.  ``qvalid (Q,)`` masks padded query rows out of the
+    histogram and the page counts ONLY (their dists/vids rows are
+    computed regardless and discarded by the caller, as before).
+    Navigation runs under the named scope ``navigate``.
     """
     cfg = state.cfg
     nprobe = cfg.nprobe if nprobe is None else nprobe
-    nav_d, pids = navigate(state, queries, nprobe)  # (Q, nprobe)
-    probe_valid = nav_d < MASK_DISTANCE / 2
-    d, v = scan_and_reduce(
+    with jax.named_scope("navigate"):
+        nav_d, pids = navigate(state, queries, nprobe)  # (Q, nprobe)
+        probe_valid = nav_d < MASK_DISTANCE / 2
+    counted = probe_valid if qvalid is None else probe_valid & qvalid[:, None]
+    d, v, pages = scan_and_reduce(
         state, queries, pids, probe_valid,
         k=k, probe_chunk=probe_chunk,
         use_pallas_scan=use_pallas_scan, scan_schedule=scan_schedule,
+        counted=counted,
     )
     if not with_access:
         return d, v
-    counted = probe_valid if qvalid is None else probe_valid & qvalid[:, None]
-    return d, v, probe_histogram(cfg, pids, counted)
+    return d, v, jnp.concatenate([probe_histogram(cfg, pids, counted), pages])
 
 
 # ---------------------------------------------------------------------------
@@ -1289,51 +1310,52 @@ def maintenance_round(
     its own jit cache entry, so pre-telemetry call sites and old WAL
     records trace byte-identical graphs.
     """
-    cfg = state.cfg
-    k = int(jobs_per_round or cfg.jobs_per_round)
-    k = max(1, min(k, cfg.num_postings_cap // 2))
+    with jax.named_scope("maintain"):
+        cfg = state.cfg
+        k = int(jobs_per_round or cfg.jobs_per_round)
+        k = max(1, min(k, cfg.num_postings_cap // 2))
 
-    if access is not None:
-        tel = state.telemetry
-        state = state.replace(
-            telemetry=tel.replace(
-                access_count=tel.access_count + access.astype(jnp.int32)
+        if access is not None:
+            tel = state.telemetry
+            state = state.replace(
+                telemetry=tel.replace(
+                    access_count=tel.access_count + access.astype(jnp.int32)
+                )
             )
+
+        split_pids, split_enable, merge_pids, merge_enable = _select_jobs(state, k)
+        if not cfg.enable_merge:
+            merge_enable = jnp.zeros_like(merge_enable)
+
+        state, split_acted, s_cand = _split_jobs(
+            state, split_pids.astype(jnp.int32), split_enable
+        )
+        # Merges run after the splits (freed split pids are already invalid, so
+        # they can't be picked as absorb targets); every ENABLED merge source
+        # is barred as a target for every job — disabled rows are top_k filler
+        # indices that must stay eligible as targets.
+        state, merge_acted, m_cand = _merge_jobs(
+            state, merge_pids.astype(jnp.int32), merge_enable,
+            jnp.where(merge_enable, merge_pids, -1).astype(jnp.int32),
         )
 
-    split_pids, split_enable, merge_pids, merge_enable = _select_jobs(state, k)
-    if not cfg.enable_merge:
-        merge_enable = jnp.zeros_like(merge_enable)
+        if cfg.enable_reassign:
+            cand = tuple(
+                jnp.concatenate([a, b], axis=0) for a, b in zip(s_cand, m_cand)
+            )
+            # Evaluation budget scales with the round's job count (overflow is
+            # counted); the mover compaction inside keeps the append scatter at
+            # reassign_budget rows regardless.  One wide GEMM + one scatter for
+            # the whole round instead of two of each per job.
+            state = _execute_reassigns(
+                state, *cand,
+                budget=max(cfg.reassign_budget, k * cfg.reassign_budget // 2),
+            )
 
-    state, split_acted, s_cand = _split_jobs(
-        state, split_pids.astype(jnp.int32), split_enable
-    )
-    # Merges run after the splits (freed split pids are already invalid, so
-    # they can't be picked as absorb targets); every ENABLED merge source
-    # is barred as a target for every job — disabled rows are top_k filler
-    # indices that must stay eligible as targets.
-    state, merge_acted, m_cand = _merge_jobs(
-        state, merge_pids.astype(jnp.int32), merge_enable,
-        jnp.where(merge_enable, merge_pids, -1).astype(jnp.int32),
-    )
-
-    if cfg.enable_reassign:
-        cand = tuple(
-            jnp.concatenate([a, b], axis=0) for a, b in zip(s_cand, m_cand)
+        did = jnp.sum(split_acted.astype(jnp.int32)) + jnp.sum(
+            merge_acted.astype(jnp.int32)
         )
-        # Evaluation budget scales with the round's job count (overflow is
-        # counted); the mover compaction inside keeps the append scatter at
-        # reassign_budget rows regardless.  One wide GEMM + one scatter for
-        # the whole round instead of two of each per job.
-        state = _execute_reassigns(
-            state, *cand,
-            budget=max(cfg.reassign_budget, k * cfg.reassign_budget // 2),
-        )
-
-    did = jnp.sum(split_acted.astype(jnp.int32)) + jnp.sum(
-        merge_acted.astype(jnp.int32)
-    )
-    return state, did
+        return state, did
 
 
 @functools.lru_cache(maxsize=None)

@@ -560,7 +560,10 @@ class ShardedIndex(DurableBackend):
                 scan_schedule=self.scan_schedule,
             )
             self._search_steps[key] = step
-        d, v = step(self.stacked, jnp.asarray(queries), self.shard_alive)
+        # a copy: the queue refills its staging buffer while the dispatch
+        # may still read it (on the CPU the device array aliases it)
+        d, v = step(self.stacked, jnp.asarray(np.array(queries)),
+                    self.shard_alive)
 
         def finalize():
             return np.asarray(d), np.asarray(v)

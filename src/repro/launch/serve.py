@@ -39,6 +39,21 @@ def _print_report(service) -> None:
     print(f"queue: batches={q['batches']} rows={q['rows']} "
           f"pad_waste={q['padding_waste_frac']:.3f} "
           f"depth_avg={q['depth_rows_avg']:.0f} depth_max={q['depth_rows_max']}")
+    # the span and counter totals (report()["counters"]): where a search
+    # waits, how busy the pump and the WAL were, the WAL's bytes a record,
+    # how much of the batched scan's page grid was used and whether its
+    # budget dropped pages
+    c = rep["counters"]
+    wait = c.get("queue.wait_s.search", 0.0)
+    wal_s = c.get("span_s.wal.append", 0.0) + c.get("wal.sync_s", 0.0)
+    wal_kb = c.get("wal.bytes", 0) / 1024 / max(c.get("span_n.wal.append", 0), 1)
+    use = c.get("scan.pages_unique", 0) / max(c.get("scan.pages_grid", 0), 1)
+    print(f"spans: queue_wait_ms="
+          f"{1e3 * wait / max(c.get('queue.rows.search', 0), 1):.2f} "
+          f"pump_busy_s={c.get('span_s.serve.step', 0.0):.3f} "
+          f"wal_busy_s={wal_s:.3f} wal_kb_per_record={wal_kb:.1f} "
+          f"scan_page_use={100.0 * use:.2f}% "
+          f"pages_dropped={c.get('scan.pages_dropped', 0)}")
     r = rep.get("replicas")
     if r:
         lags = [x["lag"] for x in r["per_replica"]]

@@ -33,7 +33,13 @@ pluggable :class:`~repro.serve.policy.MaintenancePolicy` — the paper's
 
 Metrics: per-op latency percentiles (bounded reservoir), queue depth,
 padding waste, and maintenance throughput/overlap — everything
-Fig. 7/9/12 plot, per policy.
+Fig. 7/9/12 plot, per policy.  Every layer boundary of the serve path is
+a span (``repro.utils.spans``): on the pump thread ``serve.step`` (one
+iteration's work under ``_work``: a batch, or the idle branch's
+readbacks, ack and maintenance slot) and ``serve.idle`` (the wait for
+work), and inside a step ``serve.dispatch``, ``serve.readback``,
+``serve.update``, ``serve.ack`` and ``serve.maintain``.  Their totals
+and those of the queue, the WAL and the scan are ``report()["counters"]``.
 """
 from __future__ import annotations
 
@@ -47,12 +53,14 @@ from typing import Callable, Protocol
 
 import numpy as np
 
+from repro.core import lire
 from repro.core.index import SPFreshIndex
 from repro.serve.ownership import (
     GUARDED, INIT, LIFECYCLE, PUMP, holds_work, install_lock_check,
 )
 from repro.serve.policy import BacklogPolicy, MaintenancePolicy, RatioPolicy
 from repro.storage.durability import DurableBackend
+from repro.utils.spans import add, span
 from repro.serve.queue import (
     DELETE, INSERT, SEARCH, MicroBatch, RequestQueue, Ticket, default_buckets,
 )
@@ -146,6 +154,9 @@ class LocalBackend(DurableBackend):
         self._pending_access = np.zeros(
             (index.state.cfg.num_postings_cap,), np.int64
         )
+        # scan.* page totals of the batched scan, read back with each
+        # dispatch's histogram (engine.report()["counters"])
+        self.counters: dict[str, float] = {}
 
     def search(self, queries, k, nprobe, valid=None):
         return self.search_begin(queries, k, nprobe, valid)()
@@ -157,7 +168,8 @@ class LocalBackend(DurableBackend):
         engine's pump thread defers ``finalize`` to scatter time so the
         device overlaps it with the next batch's work.  Access telemetry
         is folded into ``_pending_access`` at finalize time, always
-        before the next maintenance dispatch drains it."""
+        before the next maintenance dispatch drains it, and the
+        dispatch's page counts into ``counters``."""
         if not self.track_access:
             out = self.index.search_padded(
                 queries, k, nprobe=nprobe, probe_chunk=self.probe_chunk,
@@ -176,8 +188,13 @@ class LocalBackend(DurableBackend):
         )
 
         def finalize():
-            d, v, hist = (np.asarray(x) for x in out)
+            d, v, access = (np.asarray(x) for x in out)
+            hist, pages = lire.split_access(access)
             self._pending_access += hist
+            c = self.counters
+            add(c, "scan.pages_unique", int(pages[0]))
+            add(c, "scan.pages_dropped", int(pages[1]))
+            add(c, "scan.pages_grid", int(pages[2]))
             return d, v
         return finalize
 
@@ -409,10 +426,10 @@ class ServeMetrics:
         }
         # tickets complete from the pump AND from replica worker threads
         self._note_lock = threading.Lock()
-        self.maint_slots = 0
+        # slot count and seconds are the serve.maintain spans
+        # (ServeEngine.counters)
         self.maint_rounds = 0
         self.maint_steps = 0
-        self.maint_time_s = 0.0
         # async-mode split: slots run in queue-idle gaps (overlapped with
         # nothing on the serve path) vs deferred/forced under pressure
         self.maint_idle_slots = 0
@@ -430,10 +447,8 @@ class ServeMetrics:
 
     def note_maintenance(self, steps: int, dt: float, rounds: int = 1,
                          idle: bool = False) -> None:
-        self.maint_slots += 1
         self.maint_rounds += rounds
         self.maint_steps += steps
-        self.maint_time_s += dt
         if idle:
             self.maint_idle_slots += 1
             self.maint_idle_time_s += dt
@@ -488,6 +503,7 @@ class ServeEngine:
         # bound once in __init__, immutable after
         "cfg": INIT, "backend": INIT, "policy": INIT, "queue": INIT,
         "metrics": INIT, "_work": INIT, "_stop": INIT, "replicas": INIT,
+        "counters": INIT,
         # shared mutable pipeline state: only under _work
         "_inflight": GUARDED, "_unacked": GUARDED, "_maint_due": GUARDED,
         # pump-thread-only writes; racy reads are benign by design
@@ -521,6 +537,8 @@ class ServeEngine:
             max_wait_ms=self.cfg.max_wait_ms if self.cfg.async_serve else 0.0,
         )
         self.metrics = ServeMetrics(self.cfg.lat_reservoir)
+        # span_s.* / span_n.* of the serve.* spans (repro.utils.spans)
+        self.counters: dict[str, float] = {}
         # read replicas (a bound ReplicaSet, distributed/replication.py):
         # the pump offers every SEARCH batch to replicas.route() first
         self.replicas = replicas
@@ -601,28 +619,27 @@ class ServeEngine:
                     # not blocked behind the window
                     batch = self.queue.pop_batch()
                     if batch is not None:
-                        with self._work:
+                        with self._work, self._step_span(batch):
                             self._process_async(batch)
                     continue
                 # queue idle: land deferred readbacks, cross the ack
                 # point, then give the rebuilder ONE slot (re-checking
                 # for arrivals between slots keeps bursts unblocked)
                 with self._work:
-                    self._drain_inflight()
-                    self._ack_updates()
-                    if self._idle_maintenance():
+                    if self._idle_step():
                         continue
                 self._busy = False
-                self.queue.wait_nonempty(0.05)
+                with span("serve.idle", self.counters):
+                    self.queue.wait_nonempty(0.05)
             # shutdown drain: nothing may be stranded behind the stop
             with self._work:
                 while True:
                     batch = self.queue.pop_batch(force=True)
                     if batch is None:
                         break
-                    self._process_async(batch)
-                self._drain_inflight()
-                self._ack_updates()
+                    with self._step_span(batch):
+                        self._process_async(batch)
+                self._idle_step(maintain=False)
                 self._busy = False
         except BaseException as e:  # noqa: BLE001 — surfaced to waiters
             self._pump_error = e
@@ -630,6 +647,25 @@ class ServeEngine:
             log.exception(
                 "serve pump thread died; pending tickets will raise"
             )
+
+    def _step_span(self, batch: MicroBatch) -> span:
+        """The ``serve.step`` span of one popped batch."""
+        return span("serve.step", self.counters, batch=batch.seq,
+                    op=batch.op, rows=batch.n_valid, bucket=batch.bucket)
+
+    @holds_work
+    def _idle_step(self, maintain: bool = True) -> bool:
+        """The idle branch's work as one ``serve.step`` span with op
+        ``idle`` (none when there is nothing to do): land deferred
+        readbacks, cross the ack point, then, with ``maintain``, run ONE
+        deferred maintenance slot.  Returns whether a slot ran."""
+        if not (self._inflight or self._unacked
+                or (maintain and self._maint_due > 0)):
+            return False
+        with span("serve.step", self.counters, op="idle"):
+            self._drain_inflight()
+            self._ack_updates()
+            return maintain and self._idle_maintenance()
 
     @holds_work
     def _process_async(self, batch: MicroBatch) -> None:
@@ -712,7 +748,7 @@ class ServeEngine:
             # Cooperative pumping can race with another caller thread's
             # drain()/exclusive(); dispatch under _work like every other
             # path (uncontended re-entrant acquire when single-threaded).
-            with self._work:
+            with self._work, self._step_span(batch):
                 self._process(batch)
             n += 1
         return n
@@ -757,27 +793,37 @@ class ServeEngine:
             k, nprobe = batch.key
             # batch.valid masks padded rows out of the access telemetry
             # (their result rows are computed and discarded, as before).
-            if self.is_async:
-                begin = getattr(self.backend, "search_begin", None)
-                if begin is not None:
-                    # dispatch now, read back at scatter time: the device
-                    # overlaps this batch with whatever the pump does next
+            begin = getattr(self.backend, "search_begin", None)
+            if begin is None:
+                with span("serve.dispatch", self.counters, batch=batch.seq):
+                    d, v = self.backend.search(
+                        batch.arrays["queries"], k, nprobe, batch.valid
+                    )
+                batch.scatter({"dists": d, "ids": v})
+            else:
+                with span("serve.dispatch", self.counters, batch=batch.seq):
                     fin = begin(batch.arrays["queries"], k, nprobe,
                                 batch.valid)
+                if self.is_async:
+                    # read back at scatter time: the device overlaps this
+                    # batch with whatever the pump does next
                     self._inflight.append((batch, fin))
                     return
-            d, v = self.backend.search(
-                batch.arrays["queries"], k, nprobe, batch.valid
-            )
-            batch.scatter({"dists": d, "ids": v})
+                with span("serve.readback", self.counters, batch=batch.seq):
+                    d, v = fin()
+                    batch.scatter({"dists": d, "ids": v})
         elif batch.op == INSERT:
-            self._process_insert(batch)
+            with span("serve.update", self.counters, batch=batch.seq,
+                      op=INSERT):
+                self._process_insert(batch)
             self._tick_background()
         else:
-            vids, valid = batch.arrays["vids"], batch.valid
-            self.backend.log_update("delete", {"vids": vids[valid]})
-            self.backend.delete(vids, valid)
-            batch.scatter({})
+            with span("serve.update", self.counters, batch=batch.seq,
+                      op=DELETE):
+                vids, valid = batch.arrays["vids"], batch.valid
+                self.backend.log_update("delete", {"vids": vids[valid]})
+                self.backend.delete(vids, valid)
+                batch.scatter({})
             self._tick_background()
         self._note_done(batch)
 
@@ -805,23 +851,25 @@ class ServeEngine:
         update ticket (latency includes the fsync wait)."""
         if not self._unacked:
             return
-        self.backend.wal_sync()
-        now = time.perf_counter()
-        for t in self._unacked:
-            t.t_done = now
-            self.metrics.note_ticket(t)
-            t._signal()
-        self._unacked.clear()
+        with span("serve.ack", self.counters, tickets=len(self._unacked)):
+            self.backend.wal_sync()
+            now = time.perf_counter()
+            for t in self._unacked:
+                t.t_done = now
+                self.metrics.note_ticket(t)
+                t._signal()
+            self._unacked.clear()
 
     @holds_work
     def _finish_one_inflight(self) -> None:
         batch, finalize = self._inflight.popleft()
-        d, v = finalize()
-        batch.scatter({"dists": d, "ids": v})
-        for part in batch.parts:
-            if part.ticket.done:
-                self.metrics.note_ticket(part.ticket)
-                part.ticket._signal()
+        with span("serve.readback", self.counters, batch=batch.seq):
+            d, v = finalize()
+            batch.scatter({"dists": d, "ids": v})
+            for part in batch.parts:
+                if part.ticket.done:
+                    self.metrics.note_ticket(part.ticket)
+                    part.ticket._signal()
 
     @holds_work
     def _drain_inflight(self) -> None:
@@ -848,10 +896,9 @@ class ServeEngine:
             if not pending.any():
                 break
             if attempt > 0:
-                t0 = time.perf_counter()
-                self._run_maintenance()      # backpressure slot
-                # stall: serve-path time burned waiting on the rebuilder
-                self.metrics.insert_stall_s += time.perf_counter() - t0
+                # stall: serve-path time burned waiting on the rebuilder,
+                # the serve.maintain span of this backpressure slot
+                self.metrics.insert_stall_s += self._run_maintenance()
                 self.metrics.insert_retries += 1
             got_ids, landed = self.backend.insert(vecs, vids, pending)
             newly = pending & landed
@@ -905,19 +952,18 @@ class ServeEngine:
         return True
 
     @holds_work
-    def _run_maintenance(self, idle: bool = False) -> int:
+    def _run_maintenance(self, idle: bool = False) -> float:
         """One maintenance slot = ONE fused round of ``policy.budget`` jobs
-        (a single dispatch; the host reads back one did-work scalar)."""
+        (a single dispatch; the host reads back one did-work scalar).
+        Returns the slot's ``serve.maintain`` seconds."""
         # deferred search readbacks fold access telemetry at finalize —
         # land them before the maintain dispatch drains that buffer
         self._drain_inflight()
-        t0 = time.perf_counter()
-        jobs = self.backend.maintain(self.policy.budget)
-        self.policy.note_maintenance(jobs)
-        self.metrics.note_maintenance(
-            jobs, time.perf_counter() - t0, idle=idle
-        )
-        return jobs
+        with span("serve.maintain", self.counters, idle=idle) as sp:
+            jobs = self.backend.maintain(self.policy.budget)
+            self.policy.note_maintenance(jobs)
+        self.metrics.note_maintenance(jobs, sp.s, idle=idle)
+        return sp.s
 
     def drain(self) -> int:
         """Flush the queue, then run the rebuilder to quiescence (batched
@@ -926,11 +972,9 @@ class ServeEngine:
         with self._work:
             self._drain_inflight()
             self._maint_due = 0    # quiescence supersedes deferred slots
-            t0 = time.perf_counter()
-            jobs, rounds = self.backend.drain()
-            self.metrics.note_maintenance(
-                jobs, time.perf_counter() - t0, rounds=rounds
-            )
+            with span("serve.maintain", self.counters, drain=True) as sp:
+                jobs, rounds = self.backend.drain()
+            self.metrics.note_maintenance(jobs, sp.s, rounds=rounds)
         return jobs
 
     # ------------------------- sync conveniences ------------------------
@@ -955,7 +999,8 @@ class ServeEngine:
 
     def report(self) -> dict:
         m = self.metrics
-        mt = m.maint_time_s
+        mt = self.counters.get("span_s.serve.maintain", 0.0)
+        wal = getattr(self.backend, "wal_set", None)
         return {
             "search": m.percentiles(SEARCH),
             "insert": m.percentiles(INSERT),
@@ -963,7 +1008,7 @@ class ServeEngine:
             "queue": self.queue.accounting(),
             "maintenance": {
                 "policy": self.policy.describe(),
-                "slots": m.maint_slots,
+                "slots": self.counters.get("span_n.serve.maintain", 0),
                 "rounds": m.maint_rounds,
                 "steps": m.maint_steps,   # jobs that acted (pre-round name)
                 "time_s": mt,
@@ -984,6 +1029,14 @@ class ServeEngine:
             "replicas": (
                 self.replicas.report() if self.replicas is not None else None
             ),
+            # one flat dict of monotonic totals: the serve.* spans, the
+            # queue's, and where the backend has them the scan's and its
+            # WAL's (repro.utils.spans)
+            "counters": {
+                **self.counters, **self.queue.counters,
+                **getattr(self.backend, "counters", {}),
+                **(wal.counters if wal is not None else {}),
+            },
         }
 
     def stats(self) -> dict:

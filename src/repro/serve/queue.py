@@ -41,6 +41,8 @@ from typing import Any
 
 import numpy as np
 
+from repro.utils.spans import add, span
+
 SEARCH, INSERT, DELETE = "search", "insert", "delete"
 _PAD_FILL = {"queries": 0.0, "vecs": 0.0, "vids": -1}
 
@@ -160,6 +162,7 @@ class MicroBatch:
     arrays: dict[str, np.ndarray]   # padded to ``bucket`` rows
     n_valid: int
     bucket: int
+    seq: int = 0                    # batch id: the queue's pop count
 
     @property
     def valid(self) -> np.ndarray:
@@ -205,6 +208,7 @@ class RequestQueue:
         self.max_depth_rows = 0
         self._depth_sum = 0.0
         self._depth_samples = 0
+        self.counters: dict[str, float] = {}
 
     # ------------------------------------------------------------- submit
     def submit(self, ticket: Ticket, arrays: dict[str, np.ndarray]) -> Ticket:
@@ -315,7 +319,8 @@ class RequestQueue:
                     if wait <= 0:
                         return self._form_batch()
                     self.window_waits += 1
-                    self._cond.wait(wait)
+                    with span("queue.window", self.counters, rows=rows):
+                        self._cond.wait(wait)
                     continue
                 if not block:
                     return None
@@ -349,6 +354,10 @@ class RequestQueue:
         self.real_rows += rows
         self.padded_rows += bucket - rows
         self.batches += 1
+        now = time.monotonic()
+        add(self.counters, "queue.wait_s." + op,
+            sum((now - p.t_enq) * p.n for p in parts))
+        add(self.counters, "queue.rows." + op, rows)
 
         arrays: dict[str, np.ndarray] = {}
         if not self.reuse_staging:
@@ -365,7 +374,7 @@ class RequestQueue:
                 arrays[name] = cat
             return MicroBatch(
                 op=op, key=key, parts=parts, arrays=arrays,
-                n_valid=rows, bucket=bucket,
+                n_valid=rows, bucket=bucket, seq=self.batches,
             )
         staging = self._staging.setdefault((op, key, bucket), {})
         for name in parts[0].arrays:
@@ -384,7 +393,7 @@ class RequestQueue:
             arrays[name] = buf
         return MicroBatch(
             op=op, key=key, parts=parts, arrays=arrays,
-            n_valid=rows, bucket=bucket,
+            n_valid=rows, bucket=bucket, seq=self.batches,
         )
 
     # ------------------------------------------------------------ metrics

@@ -33,6 +33,13 @@ deployment each shard node fsyncs its own device).  Updates in this repro
 are deterministically replicated to every shard, so the per-shard logs are
 replicas of one global dispatch stream; recovery takes the longest cleanly-
 readable log as authoritative and re-syncs the laggards.
+
+Spans (``repro.utils.spans``): ``wal.append`` covers ``WalSet.append``
+(encode, write, flush, and any fsync it makes), ``wal.fsync`` every
+``os.fsync`` of the append and sync paths; their counts are the WAL's
+append and fsync totals (``WalSet.stats()``).  ``WalSet.counters`` also
+holds ``wal.bytes`` (encoded bytes written to the shard logs) and
+``wal.sync_s`` (fsync seconds outside an append: forced ``sync()``).
 """
 from __future__ import annotations
 
@@ -45,6 +52,8 @@ from typing import Iterator
 
 import msgpack
 import numpy as np
+
+from repro.utils.spans import add, span
 
 _MAGIC = b"SPFW"
 _HEADER = struct.Struct("<4sI")  # magic, payload length
@@ -86,12 +95,14 @@ def _decode(body: bytes) -> WalRecord:
 class WriteAheadLog:
     """Append-only log; one per index shard."""
 
-    def __init__(self, path: str, tail: tuple[int, int] | None = None):
+    def __init__(self, path: str, tail: tuple[int, int] | None = None,
+                 counters: dict | None = None):
         """``tail`` = precomputed ``(last seqno, clean end offset)`` from
         a caller that already scanned the file (WalSet's salvage pass) —
-        skips the open-time rescan."""
+        skips the open-time rescan.  ``counters`` receives the
+        ``wal.fsync`` span's totals (a WalSet passes its own)."""
         self.path = path
-        self.n_fsyncs = 0
+        self.counters = {} if counters is None else counters
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
         self._seqno, clean_end = tail if tail is not None else self._scan_tail()
         if os.path.exists(path) and os.path.getsize(path) > clean_end:
@@ -129,10 +140,12 @@ class WriteAheadLog:
         if sync:
             self.sync()
 
-    def sync(self) -> None:
-        """fsync the log file (the group-commit window boundary)."""
-        os.fsync(self._fh.fileno())
-        self.n_fsyncs += 1
+    def sync(self) -> float:
+        """fsync the log file (the group-commit window boundary); returns
+        the fsync's seconds."""
+        with span("wal.fsync", self.counters) as sp:
+            os.fsync(self._fh.fileno())
+        return sp.s
 
     def truncate(self) -> None:
         """Called after a successful snapshot: the log restarts empty.
@@ -251,9 +264,9 @@ class WalSet:
     def __init__(self, wal_dir: str, n_shards: int):
         self.wal_dir = wal_dir
         self.n_shards = n_shards
-        self.n_appends = 0
         self.group_n = 0            # 0/1 = fsync every append (legacy)
         self.group_ms = 0.0         # 0 = no age-out, count/force only
+        self.counters: dict[str, float] = {}
         self._pending = 0
         self._pending_since = 0.0
         os.makedirs(wal_dir, exist_ok=True)
@@ -287,6 +300,7 @@ class WalSet:
             WriteAheadLog(
                 self.shard_path(i),
                 tail=(streams[i][-1].seqno if streams[i] else -1, ends[i]),
+                counters=self.counters,
             )
             for i in range(n_shards)
         ]
@@ -320,30 +334,43 @@ class WalSet:
         return self._pending
 
     @property
+    def n_appends(self) -> int:
+        """Records appended (the ``wal.append`` spans)."""
+        return self.counters.get("span_n.wal.append", 0)
+
+    @property
     def n_fsyncs(self) -> int:
-        """Total os.fsync calls across the shard logs' append/sync path."""
-        return sum(log.n_fsyncs for log in self.logs)
+        """Total os.fsync calls across the shard logs' append/sync path
+        (the ``wal.fsync`` spans)."""
+        return self.counters.get("span_n.wal.fsync", 0)
 
     def append(self, op: str, payload: dict[str, np.ndarray]) -> int:
         seqno = self.next_seqno
-        blob = _encode(WalRecord(op=op, payload=payload, seqno=seqno))
-        self._boot_streams = None
-        self.n_appends += 1
-        for log in self.logs:
-            log._seqno = seqno
-            log.append_encoded(blob, sync=not self.grouped)
-        if self.grouped:
-            if self._pending == 0:
-                self._pending_since = time.monotonic()
-            self._pending += 1
-            aged = (
-                self.group_ms > 0
-                and (time.monotonic() - self._pending_since) * 1e3
-                >= self.group_ms
-            )
-            if self._pending >= self.group_n or aged:
-                self.sync()
+        with span("wal.append", self.counters, op=op, seqno=seqno):
+            blob = _encode(WalRecord(op=op, payload=payload, seqno=seqno))
+            self._boot_streams = None
+            for log in self.logs:
+                log._seqno = seqno
+                log.append_encoded(blob, sync=not self.grouped)
+            add(self.counters, "wal.bytes", len(blob) * len(self.logs))
+            if self.grouped:
+                if self._pending == 0:
+                    self._pending_since = time.monotonic()
+                self._pending += 1
+                aged = (
+                    self.group_ms > 0
+                    and (time.monotonic() - self._pending_since) * 1e3
+                    >= self.group_ms
+                )
+                if self._pending >= self.group_n or aged:
+                    self._sync_logs()
         return seqno
+
+    def _sync_logs(self) -> float:
+        """One fsync round over all shard logs; returns its seconds."""
+        s = sum(log.sync() for log in self.logs)
+        self._pending = 0
+        return s
 
     def sync(self) -> None:
         """Force the group-commit window closed: one fsync round over all
@@ -351,9 +378,7 @@ class WalSet:
         ack point for the dispatches it covers).  No-op when clean."""
         if self._pending == 0:
             return
-        for log in self.logs:
-            log.sync()
-        self._pending = 0
+        add(self.counters, "wal.sync_s", self._sync_logs())
 
     def recover_records(self) -> list[WalRecord]:
         """Authoritative post-crash record stream (see class docstring)."""

@@ -278,9 +278,9 @@ def test_search_probe_histogram_counts_and_qvalid_mask():
     state = idx.state
     rng = np.random.default_rng(1)
     q = rng.normal(size=(8, 16)).astype(np.float32)
-    d, v, hist = lire.search(state, jnp.asarray(q), k=10, nprobe=4,
-                             with_access=True)
-    hist = np.asarray(hist)
+    d, v, acc = lire.search(state, jnp.asarray(q), k=10, nprobe=4,
+                            with_access=True)
+    hist, _ = lire.split_access(np.asarray(acc))
     assert hist.shape == (state.cfg.num_postings_cap,)
     assert hist.sum() == 8 * 4, "every (query, probe) counted once"
     assert (hist[~np.asarray(state.centroid_valid)] == 0).all()
@@ -288,9 +288,10 @@ def test_search_probe_histogram_counts_and_qvalid_mask():
     # qvalid masks padded rows out of the HISTOGRAM only
     qv = np.zeros(8, bool)
     qv[:3] = True
-    d2, v2, hist2 = lire.search(state, jnp.asarray(q), k=10, nprobe=4,
-                                with_access=True, qvalid=jnp.asarray(qv))
-    assert np.asarray(hist2).sum() == 3 * 4
+    d2, v2, acc2 = lire.search(state, jnp.asarray(q), k=10, nprobe=4,
+                               with_access=True, qvalid=jnp.asarray(qv))
+    hist2, _ = lire.split_access(np.asarray(acc2))
+    assert hist2.sum() == 3 * 4
     np.testing.assert_array_equal(np.asarray(d2), np.asarray(d))
     np.testing.assert_array_equal(np.asarray(v2), np.asarray(v))
 

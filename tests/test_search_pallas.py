@@ -10,6 +10,7 @@ instead (any positional difference must be a sub-tolerance distance tie).
 """
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -19,6 +20,7 @@ import pytest
 pytestmark = pytest.mark.gate
 
 from repro.core import lire
+from repro.core.distance import MASK_DISTANCE
 from repro.core.index import SPFreshIndex
 from tests.conftest import make_clustered
 from tests.test_lire import small_cfg
@@ -157,6 +159,98 @@ def test_batched_page_budget_overflow_degrades_gracefully(rng):
     )
     d0, v0 = lire.search(idx.state, queries, k=10, nprobe=8)
     np.testing.assert_array_equal(np.asarray(v0), np.asarray(v2))
+
+
+def _dedup_counts(state, queries, nprobe, budget, rows=None):
+    """``dedup_pages`` over the batch's page table, outside ``search``:
+    ``(distinct pages, pages over the budget, probed pages, table size,
+    probed pages of the rows ``rows`` marks that the budget dropped)``."""
+    from repro.kernels.posting_scan import ops as scan_ops
+
+    rows = np.ones(queries.shape[0], bool) if rows is None else rows
+
+    @jax.jit
+    def f(state, queries, rows):
+        nav_d, pids = lire.navigate(state, queries, nprobe)
+        counted = (nav_d < MASK_DISTANCE / 2) & rows[:, None]
+        flat = lire._page_table(state, pids, counted).reshape(-1)
+        _, member_pos, n_unique, overflow = scan_ops.dedup_pages(
+            flat, budget=budget, num_blocks=state.cfg.num_blocks)
+        dropped = jnp.sum((flat >= 0) & (member_pos < 0))
+        return n_unique, overflow, jnp.sum(flat >= 0), flat.size, dropped
+
+    return [int(x) for x in f(state, queries, jnp.asarray(rows))]
+
+
+def _page_counts(state, queries, **kw):
+    *_, access = lire.search(state, queries, k=10, nprobe=8,
+                             with_access=True, **kw)
+    return lire.split_access(np.asarray(access))[1].tolist()
+
+
+def test_search_page_counts_match_dedup_pages(rng):
+    """``search(with_access=True)`` returns the page counts the batched
+    scan's dedup computed; the other paths count every probed page."""
+    idx, queries = _churned_index(rng)
+    cfg = idx.state.cfg
+    budget = min(queries.shape[0] * 8 * cfg.max_blocks_per_posting,
+                 cfg.num_blocks)
+    state = idx.state.replace(
+        cfg=dataclasses.replace(cfg, scan_page_budget=budget))
+    n_unique, overflow, probed, grid, _ = _dedup_counts(
+        state, queries, 8, budget)
+    assert overflow == 0 and 0 < n_unique < probed
+    assert _page_counts(state, queries, use_pallas_scan=True,
+                        scan_schedule="batched") == [n_unique, 0, budget]
+    for kw in ({"use_pallas_scan": True, "scan_schedule": "per_query"}, {}):
+        assert _page_counts(state, queries, **kw) == [probed, 0, grid], kw
+
+
+def test_search_page_counts_leave_padding_rows_out(rng):
+    """Rows ``qvalid`` marks as padding probe pages of their own, which
+    the scan streams but the page counts leave out."""
+    idx, queries = _churned_index(rng)
+    cfg = idx.state.cfg
+    budget = min(queries.shape[0] * 8 * cfg.max_blocks_per_posting,
+                 cfg.num_blocks)
+    state = idx.state.replace(
+        cfg=dataclasses.replace(cfg, scan_page_budget=budget))
+    rows = np.arange(queries.shape[0]) < queries.shape[0] // 2
+    n_all = _dedup_counts(state, queries, 8, budget)[0]
+    n_unique, _, probed, grid, _ = _dedup_counts(state, queries, 8, budget,
+                                                 rows)
+    assert 0 < n_unique < n_all
+    qv = jnp.asarray(rows)
+    assert _page_counts(state, queries, qvalid=qv, use_pallas_scan=True,
+                        scan_schedule="batched") == [n_unique, 0, budget]
+    for kw in ({"use_pallas_scan": True, "scan_schedule": "per_query"}, {}):
+        assert _page_counts(state, queries, qvalid=qv, **kw) == [
+            probed, 0, grid], kw
+
+
+def test_search_page_counts_show_dropped_pages(rng):
+    """A starved page budget: the dispatch reports the probed pages it
+    dropped, once per probing query."""
+    idx, queries = _churned_index(rng)
+    state = idx.state.replace(
+        cfg=dataclasses.replace(idx.state.cfg, scan_page_budget=16))
+    n_unique, overflow, _, _, dropped = _dedup_counts(state, queries, 8, 16)
+    assert overflow > 0 and n_unique == 16 + overflow
+    assert dropped >= overflow
+    assert _page_counts(state, queries, use_pallas_scan=True,
+                        scan_schedule="batched") == [16, dropped, 16]
+
+
+@pytest.mark.parametrize("path", ["oracle", *SCHEDULES])
+def test_search_with_access_keeps_answers_bit_identical(rng, path):
+    idx, queries = _churned_index(rng)
+    kw = {} if path == "oracle" else {"use_pallas_scan": True,
+                                       "scan_schedule": path}
+    d0, v0 = lire.search(idx.state, queries, k=10, nprobe=8, **kw)
+    d1, v1, _ = lire.search(idx.state, queries, k=10, nprobe=8,
+                            with_access=True, **kw)
+    np.testing.assert_array_equal(np.asarray(d0), np.asarray(d1))
+    np.testing.assert_array_equal(np.asarray(v0), np.asarray(v1))
 
 
 @pytest.mark.parametrize("schedule", SCHEDULES)

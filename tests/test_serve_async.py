@@ -104,6 +104,18 @@ def test_async_pump_error_surfaces_at_result(rng):
         eng.shutdown()
 
 
+def test_dispatch_arguments_own_their_memory():
+    """The queue refills a staging buffer as soon as its dispatch returns,
+    so the padded entry points' device arguments must not alias it (on
+    the CPU ``jnp.asarray`` would, under an asynchronous dispatch)."""
+    from repro.core.index import _owned
+
+    buf = np.zeros((32, 8), np.float32)
+    arg = _owned(buf)
+    buf[:] = 1.0
+    assert float(np.asarray(arg).sum()) == 0.0
+
+
 # ---------------------------------------------------------------------------
 # Multi-threaded stress: integrity, conservation, no deadlock
 # ---------------------------------------------------------------------------
