@@ -142,7 +142,9 @@ class _SeqBackend(LocalBackend):
     (the PR-1 path) instead of the batched round — the insert-stall
     baseline."""
 
-    def maintain(self, jobs):
+    def maintain(self, jobs, force=None):
+        # the baseline's slot picks its jobs by size alone: ``force``,
+        # the backpressure's full postings, is not steered
         return self.index.maintain_fused_seq(jobs)
 
 

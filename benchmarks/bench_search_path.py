@@ -137,8 +137,8 @@ def run_json(quick: bool = True) -> dict:
         scan_schedule="batched", with_access=True,
     )
     _, pages = lire.split_access(np.asarray(access))
-    pstats = dict(zip(("pages_unique", "pages_dropped", "pages_grid"),
-                      (int(x) for x in pages)))
+    pstats = dict(zip(("pages_unique", "pages_dropped", "pages_grid",
+                       "steps_skipped"), (int(x) for x in pages)))
 
     out = {
         "workload": {

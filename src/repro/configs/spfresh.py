@@ -70,9 +70,10 @@ UPDATE_B = 4096
 PROBE_CHUNK = 0
 
 # Paged-scan production path (serve_search_paged): the batch-dedup Pallas
-# schedule with a static page budget.  32768 (= num_blocks/8) caps the
-# kernel grid.  A micro-batch of Q queries probes at most Q·nprobe·MB
-# pages, and pages past the budget are dropped (counted by dedup_pages):
+# schedule with a static page cap.  A micro-batch of Q queries probes at
+# most Q·nprobe·MB pages, which sizes its kernel grid; 32768 (=
+# num_blocks/8) caps it, and pages past the cap are dropped (counted by
+# dedup_pages):
 # at Q=1024 queries spread over a 1M-vector shard that is most of them.
 # The paged service spec therefore caps its micro-batch at
 # budget / (nprobe·MB) = 128 queries, where no probe can be dropped.

@@ -249,7 +249,8 @@ def search_step(
 
 @functools.lru_cache(maxsize=None)
 def insert_step():
-    """jitted, state-donating ``(state, vecs, vids, valid) -> (state, landed)``."""
+    """jitted, state-donating ``(state, vecs, vids, valid) -> (state,
+    landed, routes)`` (`lire.insert_batch`)."""
 
     def f(state, vecs, vids, valid):
         return lire.insert_batch(state, vecs, vids, valid)
@@ -299,10 +300,12 @@ def fused_maintenance_round(jobs: int):
     paper's background job queue; the host pays one dispatch and reads one
     did-work scalar per round.  The second operand is the (P_cap,) i32
     access histogram folded into the telemetry before job selection (all
-    zeros when the caller has none — an exact no-op fold)."""
+    zeros when the caller has none — an exact no-op fold); the optional
+    third, ``(F,)`` i32 postings to split first (``maintenance_round``'s
+    ``force``)."""
 
-    def f(state, access):
-        return lire.maintenance_round(state, jobs, access)
+    def f(state, access, force=None):
+        return lire.maintenance_round(state, jobs, access, force)
 
     return jax.jit(f, donate_argnums=(0,))
 
@@ -348,8 +351,9 @@ class SPFreshIndex:
         """Foreground insert with pipeline backpressure.
 
         When a primary append hits a posting at hard capacity, we run the
-        Local Rebuilder (which splits the oversized posting) and retry the
-        unlanded vectors — the explicit-backpressure form of the paper's
+        Local Rebuilder, whose first round splits the refused rows'
+        primary postings ahead of its ranking, and retry the unlanded
+        vectors — the explicit-backpressure form of the paper's
         Updater→Rebuilder feed-forward pipeline.
         """
         vecs = np.asarray(vecs, np.float32)
@@ -368,14 +372,16 @@ class SPFreshIndex:
                 vp = _pad_to(v, _INSERT_CHUNK)
                 ip = _pad_to(i, _INSERT_CHUNK, fill=-1)
                 valid = np.arange(_INSERT_CHUNK) < nvalid
-                self.state, landed = lire.insert_batch(
+                self.state, landed, routes = lire.insert_batch(
                     self.state, jnp.asarray(vp), jnp.asarray(ip), jnp.asarray(valid)
                 )
                 landed = np.asarray(landed)[:nvalid]
                 if landed.all() or attempt == max_retries:
                     break
-                # Backpressure: let the rebuilder split the full posting(s).
-                self.maintain()
+                # Backpressure: the rebuilder splits the full primary
+                # posting(s) first, then drains the rest.
+                primary = np.asarray(routes)[:nvalid, 0]
+                self.maintain(force=primary[~landed])
                 v, i = v[~landed], i[~landed]
 
     def delete(self, vids: np.ndarray, *, log: bool = True) -> None:
@@ -394,15 +400,17 @@ class SPFreshIndex:
     # ------------------------- Local Rebuilder -------------------------
     def maintain(
         self, max_steps: int | None = None, jobs_per_round: int | None = None,
-        access: np.ndarray | None = None,
+        access: np.ndarray | None = None, force: np.ndarray | None = None,
     ) -> int:
         """Drain split/merge/reassign jobs in batched rounds (one did-work
         readback per round); returns jobs executed.  ``jobs_per_round``
         defaults to ``cfg.jobs_per_round``; the round count of the last
         drain is kept in ``last_drain_rounds``.  ``access`` (optional
-        probe histogram) folds into the first round's selection."""
+        probe histogram) folds into the first round's selection, and
+        ``force`` (optional posting ids) takes its first split slots."""
         self.state, jobs, rounds = lire.rebuild_drain(
-            self.state, max_steps, jobs_per_round, donate=True, access=access
+            self.state, max_steps, jobs_per_round, donate=True, access=access,
+            force=force,
         )
         self.last_drain_rounds = rounds
         return jobs
@@ -464,12 +472,13 @@ class SPFreshIndex:
 
     def insert_padded(
         self, vecs: np.ndarray, vids: np.ndarray, valid: np.ndarray,
-    ) -> np.ndarray:
-        """One donated-state insert dispatch; returns the landed mask."""
-        self.state, landed = insert_step()(
+    ) -> tuple[np.ndarray, np.ndarray]:
+        """One donated-state insert dispatch; returns the landed mask and
+        each row's primary posting (`lire.insert_batch`)."""
+        self.state, landed, routes = insert_step()(
             self.state, _owned(vecs), _owned(vids), _owned(valid),
         )
-        return np.asarray(landed)
+        return np.asarray(landed), np.asarray(routes)[:, 0]
 
     def delete_padded(self, vids: np.ndarray, valid: np.ndarray) -> None:
         self.state = delete_step()(
@@ -478,18 +487,22 @@ class SPFreshIndex:
 
     def maintain_round(
         self, jobs: int | None = None, access: np.ndarray | None = None,
+        force: np.ndarray | None = None,
     ) -> int:
         """One fused rebuilder round (``jobs`` split+merge jobs + one
         fused reassign pass, one dispatch); returns how many jobs acted.
         ``access`` is the serving backend's pending probe histogram
-        (None folds zeros — an exact no-op)."""
+        (None folds zeros — an exact no-op); ``force`` the postings whose
+        split takes the round's first slots."""
         jobs = jobs or self.state.cfg.jobs_per_round
         if access is None:
             access = np.zeros(
                 (self.state.cfg.num_postings_cap,), np.int32
             )
+        if force is not None:
+            force = jnp.asarray(force, jnp.int32)
         self.state, did = fused_maintenance_round(jobs)(
-            self.state, jnp.asarray(access, jnp.int32)
+            self.state, jnp.asarray(access, jnp.int32), force
         )
         return int(did)
 

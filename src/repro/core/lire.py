@@ -118,17 +118,20 @@ def probe_histogram(cfg, pids: Array, probe_valid: Array) -> Array:
 @jax.jit
 def insert_batch(
     state: IndexState, vecs: Array, vids: Array, valid: Array
-) -> tuple[IndexState, Array]:
+) -> tuple[IndexState, Array, Array]:
     """Foreground insert: route to nearest posting(s), append at tail.
 
     O(1) per append (tail-block write) — splits are *not* done here; the
     background rebuilder discovers oversized postings by length scan.
 
-    Returns ``(state, landed (B,))`` — ``landed`` is False when even the
-    *primary* (nearest-posting) append failed because the posting is at hard
-    capacity; the host Updater applies backpressure: run maintenance (which
-    splits the oversized posting) and retry.  This is the feed-forward
-    pipeline of paper §4.2 with explicit backpressure instead of threads.
+    Returns ``(state, landed (B,), routes (B, R))`` — ``landed`` is False
+    when even the *primary* (nearest-posting) append failed because the
+    posting is at hard capacity; ``routes`` are each row's routed
+    postings, nearest (the primary) first (-1 for a disabled row).  The
+    host Updater applies backpressure: a maintenance round told to split
+    the refused rows' primaries first (``maintenance_round``'s
+    ``force``), then a retry.  This is the feed-forward pipeline of paper
+    §4.2 with explicit backpressure instead of threads.
     """
     cfg = state.cfg
 
@@ -166,9 +169,13 @@ def insert_batch(
     stats = bump_stat(
         stats, "n_append_drops", jnp.sum(flat_enable & (flat_pids >= 0)) - jnp.sum(oks)
     )
+    # all R routes, not only the primary column: a second slice of the
+    # routing top-k's indices keeps the TPU compiler from lowering it to
+    # its TopK op (it sorts all P_cap centroids a row instead)
+    routes = jnp.where(valid[:, None], pids, -1)
     return state.replace(
         pool=pool, stats=stats, telemetry=telemetry, step=state.step + 1
-    ), landed
+    ), landed, routes
 
 
 @jax.jit
@@ -295,34 +302,38 @@ def _page_slot_live(state: IndexState, pages: Array) -> tuple[Array, Array]:
     return pvids, live
 
 
-N_PAGE_COUNTS = 3
+N_PAGE_COUNTS = 4
 
 
 def _page_counts(
     flat: Array, counted: Array, member_pos: Array | None = None,
-    budget: int = 0,
+    budget: int = 0, n_scanned: Array | None = None,
 ) -> Array:
     """One dispatch's page accounting, int32 ``(pages scanned, pages
-    dropped, grid)``, over the probes ``counted (Q, nprobe)`` marks (the
-    search leaves padding rows out).  ``flat`` is the ``(Q, NB)`` page
-    table.  Without ``member_pos`` (a per-query scan) every counted page
-    is scanned and the grid is the table; with the batched schedule's
-    ``member_pos`` the distinct counted pages kept in its ``budget``-page
-    grid, and the counted pages it dropped, once per probing query."""
+    dropped, grid, steps skipped)``, over the probes ``counted (Q,
+    nprobe)`` marks (the search leaves padding rows out).  ``flat`` is the
+    ``(Q, NB)`` page table.  Without ``member_pos`` (a per-query scan)
+    every counted page is scanned, the grid is the table and no step is
+    skipped; with the batched schedule's ``member_pos`` the distinct
+    counted pages kept in its ``budget``-page grid, the counted pages it
+    dropped, once per probing query, and the grid steps past the
+    ``n_scanned`` pages the kernel scored (padding rows' pages included)."""
     mb = flat.shape[1] // counted.shape[1]
     real = (flat >= 0) & jnp.repeat(counted, mb, axis=1)
     if member_pos is None:
-        return jnp.stack([jnp.sum(real), 0, flat.size]).astype(jnp.int32)
+        return jnp.stack([jnp.sum(real), 0, flat.size, 0]).astype(jnp.int32)
     real = real.reshape(-1)
     tgt = jnp.where(real & (member_pos >= 0), member_pos, budget)
     kept = jnp.zeros((budget,), bool).at[tgt].set(True, mode="drop")
     dropped = jnp.sum(real & (member_pos < 0))
-    return jnp.stack([jnp.sum(kept), dropped, budget]).astype(jnp.int32)
+    return jnp.stack(
+        [jnp.sum(kept), dropped, budget, budget - n_scanned]
+    ).astype(jnp.int32)
 
 
 def split_access(access):
     """``search(..., with_access=True)``'s third output → ``(probe
-    histogram (num_postings_cap,), page counts (3,))``."""
+    histogram (num_postings_cap,), page counts (4,))``."""
     return access[:-N_PAGE_COUNTS], access[-N_PAGE_COUNTS:]
 
 
@@ -336,7 +347,7 @@ def _pallas_scan_candidates(
     keeps only the per-page ``min(k, BS)`` nearest live candidates, so
     neither the (Q, nprobe·cap, d) gather buffer nor the (Q, nprobe·MB·BS)
     distance matrix ever exists in HBM.  Returns ``(dists (Q, n),
-    vids (Q, n), pos (Q, n), live (Q, n), pages (3,))`` with n =
+    vids (Q, n), pos (Q, n), live (Q, n), pages (4,))`` with n =
     pages·kpage; ``pos`` is each candidate's pool position
     (``block_id·BS + slot``, -1 dead), which the exact rerank gathers
     from the cold tier; ``pages`` is the dispatch's ``_page_counts`` over
@@ -348,11 +359,15 @@ def _pallas_scan_candidates(
 
     ``schedule="per_query"`` streams every probed page once per query
     (paper-faithful ParallelGET).  ``schedule="batched"`` dedups the whole
-    micro-batch's pages to a static ``scan_page_budget`` (overflow drops
-    the highest-numbered pages — see ``ops.dedup_pages``) and scores each
+    micro-batch's pages into a static grid (overflow drops the
+    highest-numbered pages — see ``ops.dedup_pages``) and scores each
     unique page against all queries with one MXU GEMM; candidates are then
     masked back to each query's own probe set, so results match the
-    per-query schedule whenever the budget holds every unique page.
+    per-query schedule whenever the grid holds every unique page.  The
+    grid is the fewest pages the batch could probe, ``Q·nprobe·MB``, the
+    pool's page count, or ``scan_page_budget`` when that caps it lower;
+    the kernel stops at the dedup's distinct pages, and the grid steps
+    past them fetch and score nothing.
     """
     cfg = state.cfg
     pool = state.pool
@@ -387,11 +402,16 @@ def _pallas_scan_candidates(
         cand_p = cand_p.reshape(q, -1)
         pages = _page_counts(flat, counted)
     elif schedule == "batched":
-        budget = cfg.scan_page_budget or min(q * nprobe * mb, cfg.num_blocks)
-        uniq, member_pos, _, _ = scan_ops.dedup_pages(
+        # a bucket of q rows probes at most q·nprobe·MB pages: a larger
+        # grid only adds steps no page can fill
+        budget = min(q * nprobe * mb, cfg.num_blocks)
+        if cfg.scan_page_budget:
+            budget = min(budget, cfg.scan_page_budget)
+        uniq, member_pos, n_unique, _ = scan_ops.dedup_pages(
             flat.reshape(-1), budget=budget, num_blocks=cfg.num_blocks
         )
-        pages = _page_counts(flat, counted, member_pos, budget)
+        n_scan = jnp.minimum(n_unique, budget)
+        pages = _page_counts(flat, counted, member_pos, budget, n_scan)
         pvids, live = _page_slot_live(state, uniq)      # (budget, BS)
         if quant:
             # invert the dedup: every original probe scatters its posting's
@@ -408,11 +428,11 @@ def _pallas_scan_candidates(
             )
             d, slots = scan_ops.scan_unique_blocks_topk_q8(
                 queries, uniq, live, pool.blocks, u_scale, u_zero,
-                k=kpage,
+                k=kpage, n_pages=n_scan,
             )                                           # (budget, kpage, Q)
         else:
             d, slots = scan_ops.scan_unique_blocks_topk(
-                queries, uniq, live, pool.blocks, k=kpage
+                queries, uniq, live, pool.blocks, k=kpage, n_pages=n_scan
             )                                           # (budget, kpage, Q)
         # gather each query's own probed pages back out of the unique-page
         # tiles (parity with the per-query schedule: a page another query
@@ -549,7 +569,7 @@ def scan_and_reduce(
     before the final top-k (the two-tier search closing the accuracy
     gap).
 
-    Returns ``(dists (Q, k), vids (Q, k), pages (3,))``: ``pages`` is the
+    Returns ``(dists (Q, k), vids (Q, k), pages (4,))``: ``pages`` is the
     dispatch's page accounting (``_page_counts``; the XLA paths count
     every probed page of the grid as scanned) over the probes ``counted
     (Q, nprobe)`` marks, by default ``probe_valid``.  Device operations carry
@@ -662,9 +682,9 @@ def search(
     ``with_access=True`` additionally returns, as a third output, the
     per-posting probe histogram (``probe_histogram``) with the dispatch's
     page counts appended, ``(pages scanned, pages dropped by the budget,
-    grid)`` (``scan_and_reduce``; ``split_access`` parts them): one
-    readback carries both.  The ``(dists, vids)`` numerics are
-    untouched.  ``qvalid (Q,)`` masks padded query rows out of the
+    grid, grid steps skipped)`` (``scan_and_reduce``; ``split_access``
+    parts them): one readback carries both.  The ``(dists, vids)``
+    numerics are untouched.  ``qvalid (Q,)`` masks padded query rows out of the
     histogram and the page counts ONLY (their dists/vids rows are
     computed regardless and discarded by the caller, as before).
     Navigation runs under the named scope ``navigate``.
@@ -1285,11 +1305,48 @@ def _select_jobs(
     return split_pids, split_enable, merge_pids, merge_enable
 
 
+def _split_forced_first(
+    state: IndexState, split_pids: Array, split_enable: Array, force: Array
+) -> tuple[Array, Array]:
+    """The round's ``K`` split jobs with the postings ``force (F,)`` names
+    in front: each named posting still valid and over ``split_limit``
+    takes one of the first slots (once, in ``force`` order), and the
+    ranked jobs ``split_pids``/``split_enable`` fill the slots left, in
+    their order, skipping the postings already forced.  The ``-1``
+    entries of ``force`` name nothing; with none named the jobs come back
+    as they were.  Every returned pid is distinct, as `_split_jobs`
+    requires."""
+    cfg = state.cfg
+    k = split_pids.shape[0]
+    force = force.astype(jnp.int32)
+    safe = jnp.maximum(force, 0)
+    named = (
+        (force >= 0) & state.centroid_valid[safe]
+        & (state.pool.posting_len[safe] > cfg.split_limit)
+    )
+    named = _dedup_vid_mask(force, named)
+    forced = jnp.zeros((cfg.num_postings_cap,), bool).at[
+        jnp.where(named, force, cfg.num_postings_cap)
+    ].set(True, mode="drop")
+    dup = forced[split_pids]
+    # slot order: forced, ranked jobs, the ranking's disabled filler, and
+    # last the entries no slot may take (duplicates, names of nothing)
+    rank = jnp.concatenate([
+        jnp.where(named, 0, 4),
+        jnp.where(dup, 3, jnp.where(split_enable, 1, 2)),
+    ])
+    order = jnp.argsort(rank, stable=True)[:k]
+    pids = jnp.concatenate([safe, split_pids.astype(jnp.int32)])
+    enable = jnp.concatenate([named, split_enable & ~dup])
+    return pids[order], enable[order]
+
+
 @functools.partial(jax.jit, static_argnames=("jobs_per_round",))
 def maintenance_round(
     state: IndexState,
     jobs_per_round: int | None = None,
     access: Array | None = None,
+    force: Array | None = None,
 ) -> tuple[IndexState, Array]:
     """One batched rebuild round: K split + K merge jobs selected by
     ``cfg.maintain_policy`` (see `_select_jobs`; disjoint pid sets —
@@ -1309,6 +1366,13 @@ def maintenance_round(
     selection.  ``None`` skips the fold entirely — an empty pytree keys
     its own jit cache entry, so pre-telemetry call sites and old WAL
     records trace byte-identical graphs.
+
+    ``force`` is an optional ``(F,) i32`` list of postings to split first
+    (-1 = none): the Updater's backpressure names the full primary
+    postings of the inserts it is retrying, and each of them still over
+    ``split_limit`` takes one of the round's first split slots ahead of
+    the ranking (`_split_forced_first`).  All -1 selects exactly what
+    ``None`` does.
     """
     with jax.named_scope("maintain"):
         cfg = state.cfg
@@ -1324,6 +1388,10 @@ def maintenance_round(
             )
 
         split_pids, split_enable, merge_pids, merge_enable = _select_jobs(state, k)
+        if force is not None:
+            split_pids, split_enable = _split_forced_first(
+                state, split_pids, split_enable, force
+            )
         if not cfg.enable_merge:
             merge_enable = jnp.zeros_like(merge_enable)
 
@@ -1362,18 +1430,10 @@ def maintenance_round(
 def _donating_round(jobs: int):
     """State-donating compile of `maintenance_round` (drain loops hand the
     round its own state back, so XLA updates the block pool in place
-    instead of copying it every round)."""
+    instead of copying it every round); ``access`` and ``force`` may be
+    None, as in `maintenance_round`."""
     return jax.jit(
-        lambda s: maintenance_round(s, jobs), donate_argnums=(0,)
-    )
-
-
-@functools.lru_cache(maxsize=None)
-def _donating_round_access(jobs: int):
-    """`_donating_round` with the access-histogram operand (first round of
-    a drain folds the backend's pending probe counts)."""
-    return jax.jit(
-        lambda s, a: maintenance_round(s, jobs, a), donate_argnums=(0,)
+        lambda s, a, f: maintenance_round(s, jobs, a, f), donate_argnums=(0,)
     )
 
 
@@ -1384,6 +1444,7 @@ def rebuild_drain(
     *,
     donate: bool = False,
     access: Array | None = None,
+    force: Array | None = None,
 ) -> tuple[IndexState, int, int]:
     """Host-driven Local Rebuilder loop in batched rounds: run
     `maintenance_round` until quiescent, reading back ONE ``did_work``
@@ -1397,25 +1458,25 @@ def rebuild_drain(
     only for callers that own them exclusively (`SPFreshIndex.maintain`).
     ``access`` (optional probe histogram) folds into the FIRST round's
     selection; later rounds of the same drain see it via the state.
+    ``force`` (optional postings to split first) steers the FIRST round's
+    split slots, as in `maintenance_round`.
     Returns ``(state, jobs_done, rounds)``.
     """
     cfg = state.cfg
     jobs = int(jobs_per_round or cfg.jobs_per_round)
     cap_jobs = max_steps if max_steps is not None else 2 * cfg.num_postings_cap
     step = _donating_round(jobs) if donate else (
-        lambda s: maintenance_round(s, jobs)
+        lambda s, a, f: maintenance_round(s, jobs, a, f)
     )
-    step_a = _donating_round_access(jobs) if donate else (
-        lambda s, a: maintenance_round(s, jobs, a)
-    )
+    if access is not None:
+        access = jnp.asarray(access, jnp.int32)
+    if force is not None:
+        force = jnp.asarray(force, jnp.int32)
     done = 0
     rounds = 0
     while done < cap_jobs:
-        if access is not None:
-            state, did = step_a(state, jnp.asarray(access, jnp.int32))
-            access = None
-        else:
-            state, did = step(state)
+        state, did = step(state, access, force)
+        access = force = None
         rounds += 1
         d = int(did)  # the round's single device→host sync
         done += d

@@ -92,10 +92,11 @@ class LireConfig:
     #   Q queries with one MXU GEMM; traffic divides by the average probe
     #   multiplicity.
     scan_schedule: str = "per_query"
-    # Static page budget for the batched schedule's fixed-shape dedup
-    # compaction.  0 = lossless auto (min(Q·nprobe·MB, num_blocks)); a
-    # smaller explicit budget bounds the kernel grid, dropping the
-    # highest-numbered pages on overflow (counted, see `dedup_pages`).
+    # Static page cap for the batched schedule's fixed-shape dedup
+    # compaction.  Its grid is the most pages a bucket of Q rows can
+    # probe, min(Q·nprobe·MB, num_blocks) (lossless); a nonzero cap below
+    # that bounds it further, dropping the highest-numbered pages on
+    # overflow (counted, see `dedup_pages`).  0 = no cap.
     scan_page_budget: int = 0
 
     @property

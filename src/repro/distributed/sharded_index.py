@@ -229,7 +229,7 @@ def make_insert_step(
         n_new = jnp.sum(mine)
         state = state.replace(next_vid=state.next_vid + n_new)
 
-        state, landed = lire.insert_batch(
+        state, landed, _ = lire.insert_batch(
             state, vecs, jnp.maximum(slots, 0), mine
         )
         # a dropped primary append (posting at hard capacity) must NOT get

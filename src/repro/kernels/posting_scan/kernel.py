@@ -26,6 +26,12 @@ same VPU idiom as ``l2_topk``.  The caller's merge works over
 matrix, which is what lets the search hot path stream pages without ever
 materializing the distance tiles in HBM.
 
+The batched ``*_topk`` forms also take the count of pages that hold work
+(the dedup's distinct pages), scalar-prefetched next to the page ids: a
+grid step at or past it fetches nothing, computes nothing and writes the
+empty page's candidates, so a grid sized for the bucket's worst case costs
+little more than the pages the batch really probed.
+
 Layout: the TPU compiler requires the last two dims of every block to be
 divisible by (8, 128) or equal to the array's.  Operands whose per-step
 row block would be 1 (a query row, a page's bias row, its [scale, zero]
@@ -55,31 +61,43 @@ _NT = (((1,), (1,)), ((), ()))   # contract the last dims: (m, d)·(n, d)ᵀ
 _SMEM_TABLE_BYTES = 256 * 1024
 
 
-def _dot_nt(a, b):
-    """``a (m, d) · b (n, d)ᵀ`` at full f32 precision."""
-    return jax.lax.dot_general(
-        a, b, _NT, precision=_HI, preferred_element_type=jnp.float32
-    )
+def _dot_nt(a, b, *, in_order: bool):
+    """``a (m, d) · b (n, d)ᵀ`` at full f32 precision.
+
+    On the MXU an entry's sum over d does not depend on how many rows
+    ``a`` has (measured on a v5e: one query row and 128 give the same
+    bits), but swapping the operands changes it.  XLA's CPU dot depends
+    on the shapes too (one row or many) and on what it fuses around the
+    dot, so under interpretation the per-query and batched schedules
+    would differ in the last bits.  ``in_order`` (set when a kernel is
+    interpreted) sums the products one coordinate after another instead,
+    the same order for every shape."""
+    if not in_order:
+        return jax.lax.dot_general(
+            a, b, _NT, precision=_HI, preferred_element_type=jnp.float32
+        )
+
+    def add_coordinate(j, acc):
+        aj = jax.lax.dynamic_slice_in_dim(a, j, 1, axis=1)     # (m, 1)
+        bj = jax.lax.dynamic_slice_in_dim(b, j, 1, axis=1)     # (n, 1)
+        return acc + aj * bj.T
+
+    acc = jnp.zeros((a.shape[0], b.shape[0]), jnp.float32)
+    return jax.lax.fori_loop(0, a.shape[1], add_coordinate, acc)
 
 
-def _page_dists(q, b):
+def _page_dists(q, b, *, in_order: bool):
     """Squared L2 ``(rows, BS)`` between queries ``q (rows, d)`` and one
     page ``b (BS, d)``, both f32: ‖q‖² − 2 q·b + ‖b‖² clamped at 0.
-    Every term is a dot over d, so `_page_dists_t` computes each entry
-    with the same arithmetic and the two schedules agree bit for bit."""
+    Both schedules call it with the queries on the left of every dot,
+    one row or a batch, so they agree bit for bit; the batched kernels
+    transpose its result to page-major ``(BS, rows)``, which puts a
+    batch of queries along the lanes."""
     ones = jnp.ones((1, q.shape[1]), jnp.float32)
-    qsq = _dot_nt(q * q, ones)                                 # (rows, 1)
-    bsq = _dot_nt(ones, b * b)                                 # (1, BS)
-    return jnp.maximum(qsq - 2.0 * _dot_nt(q, b) + bsq, 0.0)
-
-
-def _page_dists_t(q, b):
-    """`_page_dists` laid out page-major: ``(BS, rows)``, so that a batch
-    of queries runs along the lanes."""
-    ones = jnp.ones((1, q.shape[1]), jnp.float32)
-    qsq = _dot_nt(ones, q * q)                                 # (1, rows)
-    bsq = _dot_nt(b * b, ones)                                 # (BS, 1)
-    return jnp.maximum(qsq - 2.0 * _dot_nt(b, q) + bsq, 0.0)
+    qsq = _dot_nt(q * q, ones, in_order=in_order)              # (rows, 1)
+    bsq = _dot_nt(ones, b * b, in_order=in_order)              # (1, BS)
+    cross = _dot_nt(q, b, in_order=in_order)
+    return jnp.maximum(qsq - 2.0 * cross + bsq, 0.0)
 
 
 def _kmin(d, *, k: int, axis: int):
@@ -127,11 +145,11 @@ def _by_query_chunks(call, block_table, queries, blocks, *row_operands):
     return jax.tree_util.tree_map(lambda *xs: jnp.concatenate(xs), *outs)
 
 
-def _scan_per_query_kernel(table_ref, q_ref, blk_ref, out_ref):
+def _scan_per_query_kernel(table_ref, q_ref, blk_ref, out_ref, *, in_order):
     # q_ref: (1, 1, d); blk_ref: (1, BS, d); out: (1, 1, 1, BS)
     q = q_ref[0].astype(jnp.float32)               # (1, d)
     b = blk_ref[0].astype(jnp.float32)             # (BS, d)
-    out_ref[0, 0] = _page_dists(q, b)
+    out_ref[0, 0] = _page_dists(q, b, in_order=in_order)
 
 
 def _scan_per_query_rows(block_table, queries, blocks, *, interpret):
@@ -146,11 +164,12 @@ def _scan_per_query_rows(block_table, queries, blocks, *, interpret):
         ],
         out_specs=pl.BlockSpec((1, 1, 1, bs), lambda q, j, table: (q, j, 0, 0)),
     )
+    interpret = resolve_interpret(interpret)
     out = pl.pallas_call(
-        _scan_per_query_kernel,
+        functools.partial(_scan_per_query_kernel, in_order=interpret),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((q_n, nb, 1, bs), jnp.float32),
-        interpret=resolve_interpret(interpret),
+        interpret=interpret,
     )(block_table, queries[:, None, :], blocks)
     return out.reshape(q_n, nb, bs)
 
@@ -172,11 +191,11 @@ def scan_per_query(
     )
 
 
-def _scan_batched_kernel(ids_ref, q_ref, blk_ref, out_ref):
+def _scan_batched_kernel(ids_ref, q_ref, blk_ref, out_ref, *, in_order):
     # q_ref: (Q, d) resident; blk_ref: (1, BS, d); out: (1, BS, Q)
     q = q_ref[...].astype(jnp.float32)            # (Q, d)
     b = blk_ref[0].astype(jnp.float32)            # (BS, d)
-    out_ref[0] = _page_dists_t(q, b)
+    out_ref[0] = _page_dists(q, b, in_order=in_order).T
 
 
 @functools.partial(
@@ -203,11 +222,12 @@ def scan_batched(
         ],
         out_specs=pl.BlockSpec((1, bs, q_n), lambda i, ids: (i, 0, 0)),
     )
+    interpret = resolve_interpret(interpret)
     return pl.pallas_call(
-        _scan_batched_kernel,
+        functools.partial(_scan_batched_kernel, in_order=interpret),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nb, bs, q_n), jnp.float32),
-        interpret=resolve_interpret(interpret),
+        interpret=interpret,
     )(unique_blocks, queries, blocks)
 
 
@@ -216,14 +236,15 @@ def scan_batched(
 # ---------------------------------------------------------------------------
 
 def _scan_per_query_topk_kernel(
-    table_ref, q_ref, blk_ref, bias_ref, out_d_ref, out_i_ref, *, k: int
+    table_ref, q_ref, blk_ref, bias_ref, out_d_ref, out_i_ref, *, k: int,
+    in_order: bool,
 ):
     # q_ref: (1, 1, d); blk_ref: (1, BS, d); bias_ref: (1, 1, 1, BS) f32
     # (0 live, +BIG dead); out: (1, 1, 1, k) dists + slot indices within
     # the page.
     q = q_ref[0].astype(jnp.float32)              # (1, d)
     b = blk_ref[0].astype(jnp.float32)            # (BS, d)
-    d = _page_dists(q, b) + bias_ref[0, 0]        # (1, BS)
+    d = _page_dists(q, b, in_order=in_order) + bias_ref[0, 0]  # (1, BS)
     kd, ki = _kmin(d, k=k, axis=1)                # (1, k)
     out_d_ref[0, 0] = kd
     out_i_ref[0, 0] = ki
@@ -246,14 +267,17 @@ def _scan_per_query_topk_rows(block_table, queries, blocks, slot_bias, *,
             pl.BlockSpec((1, 1, 1, k), lambda q, j, table: (q, j, 0, 0)),
         ],
     )
+    interpret = resolve_interpret(interpret)
     out_d, out_i = pl.pallas_call(
-        functools.partial(_scan_per_query_topk_kernel, k=k),
+        functools.partial(
+            _scan_per_query_topk_kernel, k=k, in_order=interpret
+        ),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((q_n, nb, 1, k), jnp.float32),
             jax.ShapeDtypeStruct((q_n, nb, 1, k), jnp.int32),
         ],
-        interpret=resolve_interpret(interpret),
+        interpret=interpret,
     )(block_table, queries[:, None, :], blocks, slot_bias[:, :, None, :])
     return out_d.reshape(q_n, nb, k), out_i.reshape(q_n, nb, k)
 
@@ -280,7 +304,8 @@ def scan_per_query_topk(
 
 
 def _scan_per_query_topk_q8_kernel(
-    table_ref, q_ref, blk_ref, bias_ref, sz_ref, out_d_ref, out_i_ref, *, k: int
+    table_ref, q_ref, blk_ref, bias_ref, sz_ref, out_d_ref, out_i_ref, *,
+    k: int, in_order: bool,
 ):
     # Dequant-fused variant: blk_ref holds int8 codes; sz_ref (1, 1, 1, 2)
     # carries the page's posting [scale, zero], riding the block-table DMA
@@ -288,7 +313,7 @@ def _scan_per_query_topk_q8_kernel(
     # is reconstructed on the VPU before the distance math.
     q = q_ref[0].astype(jnp.float32)              # (1, d)
     b = _dequant(blk_ref[0], sz_ref[0, 0])        # (BS, d)
-    d = _page_dists(q, b) + bias_ref[0, 0]        # (1, BS)
+    d = _page_dists(q, b, in_order=in_order) + bias_ref[0, 0]  # (1, BS)
     kd, ki = _kmin(d, k=k, axis=1)                # (1, k)
     out_d_ref[0, 0] = kd
     out_i_ref[0, 0] = ki
@@ -312,14 +337,17 @@ def _scan_per_query_topk_q8_rows(block_table, queries, blocks, slot_bias,
             pl.BlockSpec((1, 1, 1, k), lambda q, j, table: (q, j, 0, 0)),
         ],
     )
+    interpret = resolve_interpret(interpret)
     out_d, out_i = pl.pallas_call(
-        functools.partial(_scan_per_query_topk_q8_kernel, k=k),
+        functools.partial(
+            _scan_per_query_topk_q8_kernel, k=k, in_order=interpret
+        ),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((q_n, nb, 1, k), jnp.float32),
             jax.ShapeDtypeStruct((q_n, nb, 1, k), jnp.int32),
         ],
-        interpret=resolve_interpret(interpret),
+        interpret=interpret,
     )(block_table, queries[:, None, :], blocks, slot_bias[:, :, None, :],
       page_sz[:, :, None, :])
     return out_d.reshape(q_n, nb, k), out_i.reshape(q_n, nb, k)
@@ -349,17 +377,55 @@ def scan_per_query_topk_q8(
     )
 
 
+def _page_topk_or_empty(n_ref, out_d_ref, out_i_ref, topk):
+    """Grid step ``i`` of a batched top-k kernel: below the count of pages
+    holding work ``n_ref[0]``, store ``topk()``'s ``(k, Q)`` candidates;
+    at or past it, store an empty page's — every distance BIG, slots
+    ``0..k-1`` — which is what the k-min of an all-BIG page gives."""
+    i = pl.program_id(0)
+
+    @pl.when(i < n_ref[0])
+    def _():
+        kd, ki = topk()
+        out_d_ref[0] = kd
+        out_i_ref[0] = ki
+
+    @pl.when(i >= n_ref[0])
+    def _():
+        shape = out_d_ref.shape[1:]
+        out_d_ref[0] = jnp.full(shape, BIG, jnp.float32)
+        out_i_ref[0] = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+
+
+def _held(i, n):
+    """Index-map row of grid step ``i`` for a page operand: ``i``, held at
+    the last page holding work (``n[0] - 1``) past it, so that a step past
+    it asks for the block the step before had and the pipeline fetches
+    nothing."""
+    return jnp.maximum(jnp.minimum(i, n[0] - 1), 0)
+
+
+def _page_count(n_pages, nb):
+    """The scalar-prefetch operand ``(1,) i32``: ``n_pages``, or every
+    step of the grid when None."""
+    if n_pages is None:
+        return jnp.full((1,), nb, jnp.int32)
+    return jnp.reshape(n_pages, (1,)).astype(jnp.int32)
+
+
 def _scan_batched_topk_kernel(
-    ids_ref, q_ref, blk_ref, bias_ref, out_d_ref, out_i_ref, *, k: int
+    ids_ref, n_ref, q_ref, blk_ref, bias_ref, out_d_ref, out_i_ref, *,
+    k: int, in_order: bool,
 ):
     # q_ref: (Q, d) resident; blk_ref: (1, BS, d); bias_ref: (1, BS, 1);
     # out: (1, k, Q) dists + slot indices, queries along the lanes.
-    q = q_ref[...].astype(jnp.float32)            # (Q, d)
-    b = blk_ref[0].astype(jnp.float32)            # (BS, d)
-    d = _page_dists_t(q, b) + bias_ref[0]         # (BS, Q)
-    kd, ki = _kmin(d, k=k, axis=0)                # (k, Q)
-    out_d_ref[0] = kd
-    out_i_ref[0] = ki
+    def topk():
+        q = q_ref[...].astype(jnp.float32)        # (Q, d)
+        b = blk_ref[0].astype(jnp.float32)        # (BS, d)
+        d = _page_dists(q, b, in_order=in_order).T + bias_ref[0]  # (BS, Q)
+        return _kmin(d, k=k, axis=0)              # (k, Q)
+
+    _page_topk_or_empty(n_ref, out_d_ref, out_i_ref, topk)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
@@ -370,53 +436,61 @@ def scan_batched_topk(
     slot_bias: jax.Array,      # (NB, BS) f32 — 0 live, +BIG dead
     *,
     k: int,
+    n_pages: jax.Array | None = None,  # () i32 — pages holding work
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Batch-dedup paged scan with fused per-(page, query) k-min.
 
     Returns ``(dists (NB, k, Q), slots (NB, k, Q))`` — page-major with
     the queries along the lanes, the layout in which a page's Q·k
-    candidates are stored densely."""
+    candidates are stored densely.  Only the first ``n_pages`` pages
+    (all of them when None) are fetched and scored; the rows past them
+    hold an empty page's candidates."""
     nb = unique_blocks.shape[0]
     q_n, dim = queries.shape
     _, bs, _ = blocks.shape
     assert k <= bs, (k, bs)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((q_n, dim), lambda i, ids: (0, 0)),
-            pl.BlockSpec((1, bs, dim), lambda i, ids: (ids[i], 0, 0)),
-            pl.BlockSpec((1, bs, 1), lambda i, ids: (i, 0, 0)),
+            pl.BlockSpec((q_n, dim), lambda i, ids, n: (0, 0)),
+            pl.BlockSpec((1, bs, dim),
+                         lambda i, ids, n: (ids[_held(i, n)], 0, 0)),
+            pl.BlockSpec((1, bs, 1), lambda i, ids, n: (_held(i, n), 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, k, q_n), lambda i, ids: (i, 0, 0)),
-            pl.BlockSpec((1, k, q_n), lambda i, ids: (i, 0, 0)),
+            pl.BlockSpec((1, k, q_n), lambda i, ids, n: (i, 0, 0)),
+            pl.BlockSpec((1, k, q_n), lambda i, ids, n: (i, 0, 0)),
         ],
     )
+    interpret = resolve_interpret(interpret)
     return pl.pallas_call(
-        functools.partial(_scan_batched_topk_kernel, k=k),
+        functools.partial(_scan_batched_topk_kernel, k=k, in_order=interpret),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((nb, k, q_n), jnp.float32),
             jax.ShapeDtypeStruct((nb, k, q_n), jnp.int32),
         ],
-        interpret=resolve_interpret(interpret),
-    )(unique_blocks, queries, blocks, slot_bias[:, :, None])
+        interpret=interpret,
+    )(unique_blocks, _page_count(n_pages, nb), queries, blocks,
+      slot_bias[:, :, None])
 
 
 def _scan_batched_topk_q8_kernel(
-    ids_ref, q_ref, blk_ref, bias_ref, sz_ref, out_d_ref, out_i_ref, *, k: int
+    ids_ref, n_ref, q_ref, blk_ref, bias_ref, sz_ref, out_d_ref, out_i_ref,
+    *, k: int, in_order: bool,
 ):
     # Batched dequant-fused variant: sz_ref (1, 1, 2) carries the unique
     # page's [scale, zero] (one posting owns each block, so the page has a
     # single parameter pair no matter how many queries probe it).
-    q = q_ref[...].astype(jnp.float32)            # (Q, d)
-    b = _dequant(blk_ref[0], sz_ref[0])           # (BS, d)
-    d = _page_dists_t(q, b) + bias_ref[0]         # (BS, Q)
-    kd, ki = _kmin(d, k=k, axis=0)                # (k, Q)
-    out_d_ref[0] = kd
-    out_i_ref[0] = ki
+    def topk():
+        q = q_ref[...].astype(jnp.float32)        # (Q, d)
+        b = _dequant(blk_ref[0], sz_ref[0])       # (BS, d)
+        d = _page_dists(q, b, in_order=in_order).T + bias_ref[0]  # (BS, Q)
+        return _kmin(d, k=k, axis=0)              # (k, Q)
+
+    _page_topk_or_empty(n_ref, out_d_ref, out_i_ref, topk)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
@@ -428,6 +502,7 @@ def scan_batched_topk_q8(
     page_sz: jax.Array,        # (NB, 2) f32 — per-page [scale, zero]
     *,
     k: int,
+    n_pages: jax.Array | None = None,  # () i32 — pages holding work
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Batch-dedup paged scan over int8 codes with in-kernel dequant.
@@ -438,26 +513,30 @@ def scan_batched_topk_q8(
     _, bs, _ = blocks.shape
     assert k <= bs, (k, bs)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((q_n, dim), lambda i, ids: (0, 0)),
-            pl.BlockSpec((1, bs, dim), lambda i, ids: (ids[i], 0, 0)),
-            pl.BlockSpec((1, bs, 1), lambda i, ids: (i, 0, 0)),
-            pl.BlockSpec((1, 1, 2), lambda i, ids: (i, 0, 0)),
+            pl.BlockSpec((q_n, dim), lambda i, ids, n: (0, 0)),
+            pl.BlockSpec((1, bs, dim),
+                         lambda i, ids, n: (ids[_held(i, n)], 0, 0)),
+            pl.BlockSpec((1, bs, 1), lambda i, ids, n: (_held(i, n), 0, 0)),
+            pl.BlockSpec((1, 1, 2), lambda i, ids, n: (_held(i, n), 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, k, q_n), lambda i, ids: (i, 0, 0)),
-            pl.BlockSpec((1, k, q_n), lambda i, ids: (i, 0, 0)),
+            pl.BlockSpec((1, k, q_n), lambda i, ids, n: (i, 0, 0)),
+            pl.BlockSpec((1, k, q_n), lambda i, ids, n: (i, 0, 0)),
         ],
     )
+    interpret = resolve_interpret(interpret)
     return pl.pallas_call(
-        functools.partial(_scan_batched_topk_q8_kernel, k=k),
+        functools.partial(
+            _scan_batched_topk_q8_kernel, k=k, in_order=interpret
+        ),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((nb, k, q_n), jnp.float32),
             jax.ShapeDtypeStruct((nb, k, q_n), jnp.int32),
         ],
-        interpret=resolve_interpret(interpret),
-    )(unique_blocks, queries, blocks, slot_bias[:, :, None],
-      page_sz[:, None, :])
+        interpret=interpret,
+    )(unique_blocks, _page_count(n_pages, nb), queries, blocks,
+      slot_bias[:, :, None], page_sz[:, None, :])

@@ -92,17 +92,20 @@ def scan_unique_blocks_topk(
     blocks: jax.Array,        # (B, BS, d)
     *,
     k: int,
+    n_pages: jax.Array | None = None,  # () i32 — leading rows to scan
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Batch-dedup paged scan with fused per-(page, query) k-min.
 
-    Returns ``(dists (NB, k, Q), slots (NB, k, Q))``."""
+    Returns ``(dists (NB, k, Q), slots (NB, k, Q))``.  With ``n_pages``
+    (the dedup's distinct-page count) the rows from it on are not
+    scanned: they hold an empty page's candidates, as -1 rows do."""
     bias = jnp.where(
         slot_live & (unique_blocks >= 0)[:, None], jnp.float32(0), BIG
     )
     return K.scan_batched_topk(
         jnp.maximum(unique_blocks, 0), queries, blocks, bias,
-        k=k, interpret=interpret,
+        k=k, n_pages=n_pages, interpret=interpret,
     )
 
 
@@ -143,6 +146,7 @@ def scan_unique_blocks_topk_q8(
     page_zero: jax.Array,     # (NB,) f32 — per-unique-page zero-point
     *,
     k: int,
+    n_pages: jax.Array | None = None,  # () i32 — leading rows to scan
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """`scan_unique_blocks_topk` over int8 codes with in-kernel dequant."""
@@ -155,7 +159,7 @@ def scan_unique_blocks_topk_q8(
     )                                                   # (NB, 2)
     return K.scan_batched_topk_q8(
         jnp.maximum(unique_blocks, 0), queries, blocks, bias, page_sz,
-        k=k, interpret=interpret,
+        k=k, n_pages=n_pages, interpret=interpret,
     )
 
 

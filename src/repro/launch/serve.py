@@ -41,8 +41,9 @@ def _print_report(service) -> None:
           f"depth_avg={q['depth_rows_avg']:.0f} depth_max={q['depth_rows_max']}")
     # the span and counter totals (report()["counters"]): where a search
     # waits, how busy the pump and the WAL were, the WAL's bytes a record,
-    # how much of the batched scan's page grid was used and whether its
-    # budget dropped pages
+    # how much of the batched scan's page grid was used, whether its
+    # budget dropped pages and how many grid steps it skipped, and the
+    # inserts refused and full postings split first under backpressure
     c = rep["counters"]
     wait = c.get("queue.wait_s.search", 0.0)
     wal_s = c.get("span_s.wal.append", 0.0) + c.get("wal.sync_s", 0.0)
@@ -53,7 +54,10 @@ def _print_report(service) -> None:
           f"pump_busy_s={c.get('span_s.serve.step', 0.0):.3f} "
           f"wal_busy_s={wal_s:.3f} wal_kb_per_record={wal_kb:.1f} "
           f"scan_page_use={100.0 * use:.2f}% "
-          f"pages_dropped={c.get('scan.pages_dropped', 0)}")
+          f"pages_dropped={c.get('scan.pages_dropped', 0)} "
+          f"steps_skipped={c.get('scan.steps_skipped', 0)} "
+          f"insert_dropped={rep['insert_dropped']} "
+          f"forced_splits={c.get('insert.forced_splits', 0)}")
     r = rep.get("replicas")
     if r:
         lags = [x["lag"] for x in r["per_replica"]]

@@ -88,13 +88,19 @@ class IndexBackend(Protocol):
                      ) -> Callable[[], tuple[np.ndarray, np.ndarray]]: ...
 
     def insert(self, vecs: np.ndarray, vids: np.ndarray, valid: np.ndarray
-               ) -> tuple[np.ndarray, np.ndarray]: ...
+               ) -> tuple[np.ndarray, ...]:
+        """``(ids, landed)``; a backend whose maintenance rounds take
+        ``force`` adds ``primary``, each row's nearest posting, which the
+        engine's backpressure rounds split first."""
 
     def delete(self, vids: np.ndarray, valid: np.ndarray) -> None: ...
 
     def log_update(self, op: str, payload: dict) -> None: ...
 
-    def maintain(self, jobs: int) -> int: ...
+    def maintain(self, jobs: int) -> int:
+        """One round of ``jobs`` split and merge jobs; a backend whose
+        `insert` returns ``primary`` also takes ``force=``, the postings
+        to split first."""
 
     def drain(self) -> tuple[int, int]: ...
 
@@ -195,6 +201,7 @@ class LocalBackend(DurableBackend):
             add(c, "scan.pages_unique", int(pages[0]))
             add(c, "scan.pages_dropped", int(pages[1]))
             add(c, "scan.pages_grid", int(pages[2]))
+            add(c, "scan.steps_skipped", int(pages[3]))
             return d, v
         return finalize
 
@@ -215,8 +222,8 @@ class LocalBackend(DurableBackend):
             "vids": np.asarray(vids, np.int32),
             "valid": np.asarray(valid, bool),
         })
-        landed = self.index.insert_padded(vecs, vids, valid)
-        return np.asarray(vids), landed
+        landed, primary = self.index.insert_padded(vecs, vids, valid)
+        return np.asarray(vids), landed, primary
 
     def delete(self, vids, valid):
         self._log("delete", {
@@ -235,12 +242,20 @@ class LocalBackend(DurableBackend):
         if self.index.wal is not None:
             self.index._wal_applied = self.index.wal.append(op, payload)
 
-    def maintain(self, jobs):
+    def maintain(self, jobs, force=None):
         access = self._take_access()
+        # Every round names ``jobs`` postings to split first, -1 for none,
+        # so that background and backpressure rounds run one program,
+        # the one the warm-up compiled.
+        named = np.full((jobs,), -1, np.int32)
+        if force is not None:
+            force = np.asarray(force, np.int32)[:jobs]
+            named[:len(force)] = force
         self._log("maintain", {
             "jobs": np.asarray(jobs, np.int32), "access": access,
+            "force": named,
         })
-        return self.index.maintain_round(jobs, access=access)
+        return self.index.maintain_round(jobs, access=access, force=named)
 
     def drain(self):
         # The record carries the jobs-per-round it drained with: replay
@@ -308,9 +323,12 @@ class LocalBackend(DurableBackend):
         elif rec.op == "delete":
             self.index.delete_padded(p["vids"], p["valid"])
         elif rec.op == "maintain":
-            # Old records (pre-telemetry) carry no "access" — .get(None)
-            # folds zeros, tracing the same graph those dispatches ran.
-            self.index.maintain_round(int(p["jobs"]), access=p.get("access"))
+            # Old records carry no "access" (pre-telemetry) or no "force"
+            # — .get(None) folds zeros / names nothing, tracing the same
+            # graph those dispatches ran.
+            self.index.maintain_round(
+                int(p["jobs"]), access=p.get("access"), force=p.get("force")
+            )
         elif rec.op == "drain":
             # Pre-fix records carry no "jobs" — fall back to the config
             # default those drains actually ran with.
@@ -879,9 +897,13 @@ class ServeEngine:
     @holds_work
     def _process_insert(self, batch: MicroBatch) -> None:
         """Insert with pipeline backpressure: when primary appends hit a
-        posting at hard capacity, give the rebuilder a slot (it splits the
-        oversized posting) and retry the unlanded rows — the explicit
-        backpressure form of the paper's Updater→Rebuilder pipeline."""
+        posting at hard capacity, give the rebuilder a slot and retry the
+        unlanded rows — the explicit backpressure form of the paper's
+        Updater→Rebuilder pipeline.  Where the backend reports each row's
+        primary posting, the slot's round splits the unlanded rows' full
+        primaries first (``insert.forced_splits`` counts them) and fills
+        its other split slots by the usual ranking; otherwise the round
+        runs its ranking alone."""
         vecs, vids = batch.arrays["vecs"], batch.arrays["vids"]
         valid = batch.valid
         # logged ONCE per batch (not per retry): replay re-runs the full
@@ -892,19 +914,29 @@ class ServeEngine:
         ids = np.asarray(vids).copy()
         landed_all = np.zeros(batch.bucket, bool)
         pending = valid.copy()
+        full = None
         for attempt in range(self.cfg.max_insert_retries + 1):
             if not pending.any():
                 break
             if attempt > 0:
                 # stall: serve-path time burned waiting on the rebuilder,
                 # the serve.maintain span of this backpressure slot
-                self.metrics.insert_stall_s += self._run_maintenance()
+                self.metrics.insert_stall_s += self._run_maintenance(
+                    force=full
+                )
                 self.metrics.insert_retries += 1
-            got_ids, landed = self.backend.insert(vecs, vids, pending)
+            got_ids, landed, *primary = self.backend.insert(
+                vecs, vids, pending
+            )
             newly = pending & landed
             ids[newly] = got_ids[newly]
             landed_all |= newly
             pending = pending & ~landed
+            if primary:
+                # the unlanded rows' primaries, each once, first come
+                # first: the postings the next slot splits first
+                pids = np.asarray(primary[0])[pending]
+                full = pids[np.sort(np.unique(pids, return_index=True)[1])]
         n_dropped = int(pending.sum())
         if n_dropped:
             self.metrics.insert_dropped += n_dropped
@@ -952,15 +984,24 @@ class ServeEngine:
         return True
 
     @holds_work
-    def _run_maintenance(self, idle: bool = False) -> float:
+    def _run_maintenance(
+        self, idle: bool = False, force: np.ndarray | None = None,
+    ) -> float:
         """One maintenance slot = ONE fused round of ``policy.budget`` jobs
         (a single dispatch; the host reads back one did-work scalar).
-        Returns the slot's ``serve.maintain`` seconds."""
+        ``force`` names postings whose split takes the round's first
+        slots (the insert backpressure's full primaries).  Returns the
+        slot's ``serve.maintain`` seconds."""
         # deferred search readbacks fold access telemetry at finalize —
         # land them before the maintain dispatch drains that buffer
         self._drain_inflight()
         with span("serve.maintain", self.counters, idle=idle) as sp:
-            jobs = self.backend.maintain(self.policy.budget)
+            if force is None:
+                jobs = self.backend.maintain(self.policy.budget)
+            else:
+                force = force[:self.policy.budget]
+                add(self.counters, "insert.forced_splits", len(force))
+                jobs = self.backend.maintain(self.policy.budget, force=force)
             self.policy.note_maintenance(jobs)
         self.metrics.note_maintenance(jobs, sp.s, idle=idle)
         return sp.s

@@ -128,3 +128,24 @@ def test_scan_batched_topk_q8_compiles(one_chip):
         _sds(one_chip, (budget, 2), jnp.float32),
     )
     assert "tpu_custom_call" in text
+
+
+def test_insert_step_routes_with_the_topk_op(one_chip):
+    """The insert step's routing top-k over every centroid lowers to the
+    chip's TopK op, not a sort of all of them a row, while the step also
+    returns each row's routes (the backpressure's primaries)."""
+    from repro.core import lire
+    from repro.core.types import make_empty_state
+
+    state = jax.tree.map(
+        lambda x: _sds(one_chip, x.shape, x.dtype),
+        jax.eval_shape(lambda: make_empty_state(CFG)),
+    )
+    b = 8
+    text = _compiled_text(
+        lire.insert_batch, state,
+        _sds(one_chip, (b, CFG.dim), jnp.float32),
+        _sds(one_chip, (b,), jnp.int32),
+        _sds(one_chip, (b,), jnp.bool_),
+    )
+    assert 'custom_call_target="TopK"' in text

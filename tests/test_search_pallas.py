@@ -9,6 +9,7 @@ adversarial near-duplicate workload asserts the tie-tolerant contract
 instead (any positional difference must be a sub-tolerance distance tie).
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -30,16 +31,16 @@ SCHEDULES = ("per_query", "batched")
 _CACHE: dict = {}
 
 
-def _churned_index(rng, *, near_dup=False):
+def _churned_index(rng, *, near_dup=False, codec="fp32"):
     """Build + insert + delete + maintain: splits, stale replicas, GC'd
     postings, freed pages — every masking path the scan must honor.
     Built once per workload shape (fixed seed) and cached — the index is
     read-only in every test; tests that mutate copy the state first."""
-    if near_dup in _CACHE:
-        return _CACHE[near_dup]
+    if (near_dup, codec) in _CACHE:
+        return _CACHE[near_dup, codec]
     rng = np.random.default_rng(17 if near_dup else 7)
     base = make_clustered(rng, 900, 16, n_clusters=8)
-    idx = SPFreshIndex.build(small_cfg(), base)
+    idx = SPFreshIndex.build(small_cfg(codec=codec), base)
     if near_dup:
         extra = (base[0][None, :] + 0.02 * rng.normal(size=(300, 16))
                  ).astype(np.float32)
@@ -51,8 +52,8 @@ def _churned_index(rng, *, near_dup=False):
     assert idx.stats()["n_splits"] > 0
     queries = np.concatenate([base[200:216], extra[:16]]) \
         + 0.01 * rng.normal(size=(32, 16)).astype(np.float32)
-    _CACHE[near_dup] = (idx, jnp.asarray(queries))
-    return _CACHE[near_dup]
+    _CACHE[near_dup, codec] = (idx, jnp.asarray(queries))
+    return _CACHE[near_dup, codec]
 
 
 def _assert_tie_tolerant(d0, v0, d1, v1, tol=1e-4):
@@ -201,9 +202,10 @@ def test_search_page_counts_match_dedup_pages(rng):
         state, queries, 8, budget)
     assert overflow == 0 and 0 < n_unique < probed
     assert _page_counts(state, queries, use_pallas_scan=True,
-                        scan_schedule="batched") == [n_unique, 0, budget]
+                        scan_schedule="batched") == [
+        n_unique, 0, budget, budget - n_unique]
     for kw in ({"use_pallas_scan": True, "scan_schedule": "per_query"}, {}):
-        assert _page_counts(state, queries, **kw) == [probed, 0, grid], kw
+        assert _page_counts(state, queries, **kw) == [probed, 0, grid, 0], kw
 
 
 def test_search_page_counts_leave_padding_rows_out(rng):
@@ -221,11 +223,14 @@ def test_search_page_counts_leave_padding_rows_out(rng):
                                                  rows)
     assert 0 < n_unique < n_all
     qv = jnp.asarray(rows)
+    # the kernel still scans the padding rows' pages: only the steps past
+    # all n_all distinct pages are skipped
     assert _page_counts(state, queries, qvalid=qv, use_pallas_scan=True,
-                        scan_schedule="batched") == [n_unique, 0, budget]
+                        scan_schedule="batched") == [
+        n_unique, 0, budget, budget - n_all]
     for kw in ({"use_pallas_scan": True, "scan_schedule": "per_query"}, {}):
         assert _page_counts(state, queries, qvalid=qv, **kw) == [
-            probed, 0, grid], kw
+            probed, 0, grid, 0], kw
 
 
 def test_search_page_counts_show_dropped_pages(rng):
@@ -238,7 +243,101 @@ def test_search_page_counts_show_dropped_pages(rng):
     assert overflow > 0 and n_unique == 16 + overflow
     assert dropped >= overflow
     assert _page_counts(state, queries, use_pallas_scan=True,
-                        scan_schedule="batched") == [16, dropped, 16]
+                        scan_schedule="batched") == [16, dropped, 16, 0]
+
+
+FIXED_GRID = 32_768
+
+
+def _fixed_grid_candidates(state, queries, pids, probe_valid, counted, *,
+                           k, schedule):
+    """The batched scan as it ran with a fixed page grid: ``FIXED_GRID``
+    dedup rows whatever the bucket, every grid step fetched and scored."""
+    assert schedule == "batched"
+    from repro.kernels.posting_scan import ops as scan_ops
+
+    pool, budget = state.pool, FIXED_GRID
+    q = pids.shape[0]
+    mb, bs = pool.max_blocks_per_posting, pool.block_size
+    kpage = min(k, bs)
+    flat = lire._page_table(state, pids, probe_valid)
+    safe_pp = jnp.maximum(jnp.repeat(pids, mb, axis=1), 0)
+    uniq, member_pos, _, _ = scan_ops.dedup_pages(
+        flat.reshape(-1), budget=budget, num_blocks=state.cfg.num_blocks)
+    pages = lire._page_counts(flat, counted, member_pos, budget, budget)
+    pvids, live = lire._page_slot_live(state, uniq)
+    if pool.codec == "int8":
+        tgt = jnp.where(member_pos >= 0, member_pos, budget)
+        u_scale = jnp.ones((budget,), jnp.float32).at[tgt].set(
+            pool.post_scale[safe_pp].reshape(-1), mode="drop")
+        u_zero = jnp.zeros((budget,), jnp.float32).at[tgt].set(
+            pool.post_zero[safe_pp].reshape(-1), mode="drop")
+        d, slots = scan_ops.scan_unique_blocks_topk_q8(
+            queries, uniq, live, pool.blocks, u_scale, u_zero, k=kpage)
+    else:
+        d, slots = scan_ops.scan_unique_blocks_topk(
+            queries, uniq, live, pool.blocks, k=kpage)
+    mp = member_pos.reshape(q, -1)
+    safe_mp = jnp.maximum(mp, 0)
+    qi = jnp.arange(q)[:, None]
+    slot_q = slots[safe_mp, :, qi]
+    cand_d = jnp.where((mp >= 0)[:, :, None], d[safe_mp, :, qi],
+                       MASK_DISTANCE).reshape(q, -1)
+    cand_v = jnp.take_along_axis(pvids[safe_mp], slot_q, axis=2).reshape(q, -1)
+    cand_p = jnp.where((mp >= 0)[:, :, None],
+                       uniq[safe_mp][:, :, None] * bs + slot_q, -1).reshape(q, -1)
+    return cand_d, cand_v, cand_p, cand_d < MASK_DISTANCE / 2, pages
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _candidates(scan, state, queries, qvalid):
+    """``scan``'s candidates for the batch ``search`` would hand it."""
+    nav_d, pids = lire.navigate(state, queries, 8)
+    probe_valid = nav_d < MASK_DISTANCE / 2
+    return scan(state, queries, pids, probe_valid,
+                probe_valid & qvalid[:, None], k=4, schedule="batched")
+
+
+@pytest.mark.parametrize("codec", ["fp32", "int8"])
+@pytest.mark.parametrize("bucket", [8, 16, 128])
+def test_bounded_grid_matches_fixed_grid(rng, monkeypatch, bucket, codec):
+    """The batched scan's grid bounded by the bucket (``Q·nprobe·MB`` pages
+    at most) and stopped at the batch's distinct pages answers bit for
+    bit as the fixed 32,768-page grid did, for 1..Q real rows with the
+    rest zero padding; no page is dropped, and the counts report the
+    grid steps past the distinct pages as skipped."""
+    idx, pool_q = _churned_index(rng, codec=codec)
+    state = idx.state.replace(cfg=dataclasses.replace(
+        idx.state.cfg, use_pallas_scan=True, scan_schedule="batched",
+        scan_page_budget=FIXED_GRID))
+    cfg = state.cfg
+    grid = min(bucket * 8 * cfg.max_blocks_per_posting, cfg.num_blocks)
+    noise = np.random.default_rng(bucket).normal(size=(bucket, cfg.dim))
+    rows = np.asarray(pool_q)[np.arange(bucket) % pool_q.shape[0]]
+    rows = (rows + 0.05 * noise).astype(np.float32)
+    search = functools.partial(lire.search, k=4, nprobe=8, with_access=True)
+    bounded = jax.jit(search)
+    for n_real in sorted({1, bucket // 2, bucket}):
+        real = np.arange(bucket) < n_real
+        queries = jnp.asarray(np.where(real[:, None], rows, 0.0))
+        qvalid = jnp.asarray(real)
+        # the candidates the reduce sees, then what it answers
+        for got, want in zip(_candidates(lire._pallas_scan_candidates,
+                                         state, queries, qvalid)[:3],
+                             _candidates(_fixed_grid_candidates,
+                                         state, queries, qvalid)[:3]):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        d1, v1, acc1 = bounded(state, queries, qvalid=qvalid)
+        with monkeypatch.context() as m:
+            m.setattr(lire, "_pallas_scan_candidates", _fixed_grid_candidates)
+            d0, v0, acc0 = jax.jit(search)(state, queries, qvalid=qvalid)
+        np.testing.assert_array_equal(np.asarray(v1), np.asarray(v0))
+        np.testing.assert_array_equal(np.asarray(d1), np.asarray(d0))
+        kept, dropped, g, skipped = lire.split_access(np.asarray(acc1))[1]
+        kept0, dropped0, _, _ = lire.split_access(np.asarray(acc0))[1]
+        n_all = _dedup_counts(state, queries, 8, grid)[0]
+        assert (kept, dropped, g) == (kept0, 0, grid) and dropped0 == 0
+        assert skipped == grid - n_all > 0
 
 
 @pytest.mark.parametrize("path", ["oracle", *SCHEDULES])

@@ -315,7 +315,7 @@ def _evolve_states(rng, n_steps=3):
     nid = 200
     for step in range(n_steps):
         vecs = make_clustered(rng, 12, 8, n_clusters=2)
-        state, _ = lire.insert_batch(
+        state, _, _ = lire.insert_batch(
             state, jnp.asarray(vecs),
             jnp.arange(nid, nid + 12, dtype=jnp.int32), jnp.ones(12, bool),
         )
