@@ -45,6 +45,7 @@ ROOF_BATCHES = 8         # 128-query dispatches traced for the roofline
 REF_DEPTH = 64           # reference depth before live-set filtering
 COMPLETE_GRACE_S = 60.0  # how long past the close a request may take
 STREAM_VICTIM, STREAM_SAMPLE = 21, 22
+UNKNOWN_ROW = np.iinfo(np.int64).max   # a handle that names no row
 
 
 class NoAccelerator(RuntimeError):
@@ -83,17 +84,32 @@ def enable_cache() -> str:
 def service_spec(config: dict, root: str):
     """The configuration as ``spfresh.open`` takes it.  The build's own
     seed stays the spec's default on every run: the base set is the same
-    on every seed, and so is the index built from it."""
+    on every seed, and so is the index built from it.  A ``shards``
+    section becomes the spec's ``ShardSpec`` (a mesh of
+    ``n_shards × n_replicas`` devices); without one the service runs on
+    one device."""
     import spfresh
     from repro.core.types import LireConfig
 
+    kw = {}
+    if "shards" in config:
+        kw["shards"] = spfresh.ShardSpec(**{
+            k: tuple(v) if isinstance(v, list) else v
+            for k, v in config["shards"].items()})
     return spfresh.ServiceSpec(
         index=spfresh.IndexSpec(config=LireConfig(**config["index"])),
         serve=spfresh.ServeSpec(**config["serve"]),
         scan=spfresh.ScanSpec(**config["scan"]),
         maintenance=spfresh.MaintenanceSpec(**config["maintenance"]),
         durability=spfresh.DurabilitySpec(root=root, **config["durability"]),
+        **kw,
     )
+
+
+def mesh_chips(config: dict) -> int:
+    """The devices the configuration's service runs on."""
+    sh = config.get("shards", {})
+    return int(sh.get("n_shards", 1)) * int(sh.get("n_replicas", 1))
 
 
 def buckets(config: dict) -> list[int]:
@@ -127,6 +143,7 @@ class Window:
     trace_events: list | None = None
     trace_bounds: tuple | None = None
     answered: np.ndarray | None = None   # (n,) completed (updates: acked)
+    found: dict | None = None            # search j -> (dists, rows)
     refused: np.ndarray | None = None    # (n,) an insert answered "refused"
     host: dict | None = None             # hoststall.StallWatch.summary
 
@@ -162,6 +179,11 @@ class Session:
         self.ins_ack: dict[int, float] = {}
         self.ins_sub: dict[int, float] = {}
         self.del_ack: dict[int, float] = {}
+        # rows <-> the backend's handles: None on the local backend, where
+        # a row is its own handle; on the sharded one each row's handle
+        # (-1 until assigned) and each handle's row
+        self.handle: np.ndarray | None = None
+        self.row_of: dict[int, int] = {}
         self.workdir = workdir
         self.svc = None
 
@@ -188,6 +210,50 @@ class Session:
         ids = np.asarray(ids, np.int64)
         return (ids >= 0) & (ids < self.n_base + self.cur["insert"])
 
+    # ----------------------------- handles ----------------------------
+    def handles(self, rows: np.ndarray) -> np.ndarray:
+        """The backend's handles of ``rows`` (-1 where none was assigned);
+        the rows themselves on the local backend."""
+        rows = np.asarray(rows, np.int64)
+        return rows if self.handle is None else self.handle[rows]
+
+    def rows(self, handles: np.ndarray) -> np.ndarray:
+        """The rows of the backend's ``handles``: -1 stays -1, and a
+        handle that names no row becomes ``UNKNOWN_ROW``, which the checks
+        count as an id the program made up."""
+        h = np.asarray(handles, np.int64)
+        if self.handle is None:
+            return h
+        get = self.row_of.get
+        return np.fromiter((get(x, UNKNOWN_ROW) if x >= 0 else -1
+                            for x in h.ravel().tolist()),
+                           np.int64, h.size).reshape(h.shape)
+
+    def _map(self, rows: np.ndarray, handles: np.ndarray) -> None:
+        for r, h in zip(rows.tolist(), handles.tolist()):
+            if h >= 0:
+                self.handle[r] = h
+                self.row_of[h] = r
+
+    def insert_vids(self, rows: np.ndarray) -> np.ndarray:
+        """The ``vids`` an insert of ``rows`` passes: the rows on the
+        local backend, -1 on the sharded one, which assigns handles."""
+        if self.handle is None:
+            return np.asarray(rows).astype(np.int32)
+        return np.full(len(rows), -1, np.int32)
+
+    def shard_live(self) -> list[int]:
+        """Live rows per shard by their handles (``shard * num_vectors_cap
+        + slot``): base rows and acknowledged inserts, less acknowledged
+        deletes."""
+        rows = np.concatenate([np.arange(self.n_base),
+                               np.fromiter(self.ins_ack, np.int64)])
+        rows = rows[~np.isin(rows, np.fromiter(self.del_ack, np.int64))]
+        h = self.handles(rows)
+        cap = int(self.config["index"]["num_vectors_cap"])
+        n = int(self.config["shards"]["n_shards"])
+        return np.bincount(h[h >= 0] // cap, minlength=n).tolist()
+
     # ----------------------------- set-up -----------------------------
     def open(self) -> dict:
         import spfresh
@@ -198,6 +264,10 @@ class Session:
         self.svc = spfresh.open(spec, vectors=self.base.astype(np.float32),
                                 fresh=True)
         build_s = time.perf_counter() - t
+        if spec.sharded:
+            self.handle = np.full(self.n_base + len(self.ins), -1, np.int64)
+            self._map(np.arange(self.n_base),
+                      np.asarray(self.svc.initial_handles, np.int64))
         t = time.perf_counter()
         # the build's durability point: a full snapshot before serving
         self.svc.checkpoint(delta=False)
@@ -214,9 +284,9 @@ class Session:
             eng.submit_search(self.qry[rows].astype(np.float32)).result()
             ids = self.take("insert", b)
             tk = eng.submit_insert(self.vec(ids).astype(np.float32),
-                                   ids.astype(np.int32))
+                                   self.insert_vids(ids))
             self._note_insert(ids, tk)
-            tk = eng.submit_delete(ids.astype(np.int32))
+            tk = eng.submit_delete(self.handles(ids).astype(np.int32))
             tk.result()
             for i in ids.tolist():
                 self.del_ack[i] = tk.t_done
@@ -225,7 +295,10 @@ class Session:
         return time.perf_counter() - t
 
     def _note_insert(self, ids, ticket) -> None:
-        _, landed = ticket.result()
+        got, landed = ticket.result()
+        if self.handle is not None:
+            ok = np.asarray(landed, bool)
+            self._map(ids[ok], np.asarray(got, np.int64)[ok])
         for i, ok in zip(ids.tolist(), np.asarray(landed).tolist()):
             self.ins_sub[i] = ticket.t_submit
             if ok:
@@ -233,12 +306,16 @@ class Session:
 
     # ----------------------------- counters ---------------------------
     def counters(self) -> dict:
-        """The program's own counters, read under the engine's lock."""
+        """The program's own counters, read under the engine's lock: a few
+        from its report, and every monotonic total of
+        ``report()["counters"]`` (spans, queue, scan, WAL) by its own
+        name."""
         with self.svc.engine.exclusive():
             rep = self.svc.engine.report()
             wal = self.svc.backend.wal_set.stats()
             backlog = self.svc.backlog()
-        return {"batches": rep["queue"]["batches"],
+        return {**rep["counters"],
+                "batches": rep["queue"]["batches"],
                 "rows": rep["queue"]["rows"],
                 "maint_time_s": rep["maintenance"]["time_s"],
                 "maint_slots": rep["maintenance"]["slots"],
@@ -257,6 +334,13 @@ class Session:
                            (DELETE, "victim")):
             sel = np.nonzero(sched.op == kind)[0]
             payload[sel] = self.take(name, len(sel))
+        # the vids each update passes: its row on the local backend; on
+        # the sharded one -1 for an insert and the handle of a delete's
+        # base row
+        vid = payload
+        if self.handle is not None:
+            vid = np.where(sched.op == INSERT, -1, self.handles(
+                np.where(sched.op == DELETE, payload, 0)))
         eng = self.svc.engine
         start = time.perf_counter() + 0.25
         win = Window(start=start, seconds=seconds, op=sched.op,
@@ -277,9 +361,9 @@ class Session:
                     elif op == INSERT:
                         tk = eng.submit_insert(
                             self.vec([p]).astype(np.float32),
-                            np.asarray([p], np.int32))
+                            np.asarray([vid[j]], np.int32))
                     else:
-                        tk = eng.submit_delete(np.asarray([p], np.int32))
+                        tk = eng.submit_delete(np.asarray([vid[j]], np.int32))
                     win.tickets[j] = tk
                     win.t_submit[j] = tk.t_submit
             except BaseException as e:  # noqa: BLE001 — reported below
@@ -323,19 +407,24 @@ class Session:
 
     def _await(self, win: Window) -> None:
         """Wait for every request, a minute past the close at most; note
-        the acknowledgements of the updates."""
+        the acknowledgements of the updates and each search's answers as
+        rows."""
         deadline = win.close + COMPLETE_GRACE_S
         win.answered = np.zeros(len(win.tickets), bool)
         win.refused = np.zeros(len(win.tickets), bool)
+        win.found = {}
         for j, tk in enumerate(win.tickets):
             if tk is None:
                 continue
             try:
-                tk.result(timeout=max(0.0, deadline - time.perf_counter()))
+                res = tk.result(
+                    timeout=max(0.0, deadline - time.perf_counter()))
             except TimeoutError:
                 continue
             win.answered[j] = True
-            if win.op[j] == INSERT:
+            if win.op[j] == SEARCH:
+                win.found[j] = (res[0], self.rows(res[1]))
+            elif win.op[j] == INSERT:
                 self._note_insert(win.payload[j:j + 1], tk)
                 win.refused[j] = tk.dropped > 0
             elif win.op[j] == DELETE:
@@ -343,10 +432,11 @@ class Session:
 
     # --------------------------- after the window ---------------------
     def search(self, rows_or_vecs: np.ndarray) -> tuple[np.ndarray, np.ndarray, float]:
-        """Search int8 vectors through the service; ``(dists, ids, t_submit)``."""
+        """Search int8 vectors through the service; ``(dists, rows,
+        t_submit)``."""
         tk = self.svc.engine.submit_search(rows_or_vecs.astype(np.float32))
         d, v = tk.result()
-        return np.asarray(d), np.asarray(v, np.int64), tk.t_submit
+        return np.asarray(d), self.rows(v), tk.t_submit
 
     def post_window(self) -> dict:
         """Held-out recall queries and the self-queries of acknowledged
@@ -373,30 +463,42 @@ class Session:
             out[name + "_ids"], out[name + "_d"], out[name + "_v"] = ids, dd, vv
         return out
 
-    def index_leaves(self) -> dict:
-        """The one adapter onto the index's state that the roofline reads:
-        centroids, their validity and the posting lengths."""
+    def index_leaves(self) -> list[dict]:
+        """The one adapter onto the index's state that the roofline reads,
+        one dict per shard: centroids, their validity and the posting
+        lengths.  The sharded backend's state is stacked, shard first;
+        only these leaves come to the host."""
         with self.svc.engine.exclusive():
-            st = self.svc.index.state
-            return {"centroids": np.asarray(st.centroids, np.float32),
-                    "valid": np.asarray(st.centroid_valid, bool),
-                    "posting_len": np.asarray(st.pool.posting_len, np.int64),
-                    "block_size": int(st.cfg.block_size),
-                    "dim": int(st.cfg.dim),
-                    "itemsize": int(np.dtype(st.cfg.vector_dtype).itemsize),
-                    "nprobe": int(self.config["serve"]["nprobe"])}
+            if self.svc.index is not None:
+                st = self.svc.index.state
+                cfg = st.cfg
+            else:
+                st = self.svc.backend.stacked
+                cfg = self.svc.backend.cfg
+            cen = np.asarray(st.centroids, np.float32)
+            valid = np.asarray(st.centroid_valid, bool)
+            plen = np.asarray(st.pool.posting_len, np.int64)
+        if cen.ndim == 2:
+            cen, valid, plen = cen[None], valid[None], plen[None]
+        return [{"centroids": c, "valid": v, "posting_len": p,
+                 "block_size": int(cfg.block_size), "dim": int(cfg.dim),
+                 "itemsize": int(np.dtype(cfg.vector_dtype).itemsize),
+                 "nprobe": int(self.config["serve"]["nprobe"])}
+                for c, v, p in zip(cen, valid, plen)]
 
     def roofline_batches(self, trace_dir: str) -> dict:
         """``ROOF_BATCHES`` full batches of held-out queries, one dispatch
         each, under the profiler; the work each needed, from the index's
-        state as the numpy of ``scan_need`` counts it."""
+        state as the numpy of ``scan_need`` counts it, summed over the
+        shards (every shard scans each query's ``nprobe`` nearest of its
+        own postings)."""
         import jax
 
         self.svc.engine.barrier()
-        leaves = self.index_leaves()
+        shards = self.index_leaves()
         q = self.buckets[-1]
         batches = [self.qry[self.take("query", q)] for _ in range(ROOF_BATCHES)]
-        need = [scan_need(leaves, b) for b in batches]
+        need = [scan_need(leaves, b) for b in batches for leaves in shards]
         shutil.rmtree(trace_dir, ignore_errors=True)
         jax.profiler.start_trace(trace_dir, profiler_options=_trace_options())
         with jax.profiler.TraceAnnotation(trace_reduce.SLICE_SPAN):
@@ -600,12 +702,12 @@ def check(s: Session, win: Window, post: dict, *, answers=None) -> dict:
     ref_d, _ = reference_answers(s, np.concatenate([q_s, q_p]), t_s + t_p)
     ref_s, ref_p = ref_d[:len(q_s)], ref_d[len(q_s):]
     if answers is None:
-        res = [win.tickets[j].result() for j in sample]
+        res = [win.found[j] for j in sample]
         fd = np.concatenate([r[0] for r in res]) if res else np.zeros((0, s.k))
         fv = np.concatenate([r[1] for r in res]).astype(np.int64) \
             if res else np.zeros((0, s.k), np.int64)
         pd, pv = post["recall_d"], post["recall_v"]
-        all_res = [win.tickets[j].result() for j in all_rows]
+        all_res = [win.found[j] for j in all_rows]
         all_v = np.concatenate([r[1] for r in all_res]).astype(np.int64) \
             if all_res else np.zeros((0, s.k), np.int64)
         all_t = [float(win.t_submit[j]) for j in all_rows]
@@ -667,8 +769,11 @@ def control_answers(s: Session, *, precision_bits: int = 8,
 # ---------------------------------------------------------------------------
 
 def readings(s: Session, win: Window, *, setup_s: float, recall: float,
-             roof: dict | None, device_kind: str) -> dict:
-    """Everything a metric reader (``bench/metrics/<name>.py``) may read."""
+             roof: dict | None, device_kind: str, chips: int = 1) -> dict:
+    """Everything a metric reader (``bench/metrics/<name>.py``) may read.
+    ``delta`` holds every counter of the window's close less its value
+    at the start (a counter first counted in the window from 0); device
+    numbers reduce over the ``chips`` the cell runs on."""
     lat = {"search": [], "update": []}
     done_in_window = 0
     acked_in_window = 0
@@ -685,13 +790,14 @@ def readings(s: Session, win: Window, *, setup_s: float, recall: float,
                 acked_in_window += 1
     c0, c1 = win.counters0, win.counters1
     return {
+        "chips": chips,
         "setup_s": setup_s,
         "window_s": win.seconds,
         "lat_ms": {k: np.asarray(v) for k, v in lat.items()},
         "searches_done_in_window": done_in_window,
         "updates_acked_in_window": acked_in_window,
         "recall_at_10": recall,
-        "delta": {k: c1[k] - c0[k] for k in c0},
+        "delta": {k: c1[k] - c0.get(k, 0) for k in c1},
         "backlog_end": c1["backlog"],
         "host": win.host,
         "trace_events": win.trace_events,
@@ -718,13 +824,19 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
              workdir: pathlib.Path | None = None) -> dict:
     """One run of ``workload``; returns the result line's object.
     ``config`` / ``traffic`` replace the files the cell names, and
-    ``workdir`` the durable root (tests run a cell at a size the CPU
-    holds)."""
+    ``workdir`` the durable root, with the traces beside it (tests run
+    a cell at a size the CPU holds, several at once).  A cell's
+    ``chips`` has to be the devices its configuration's mesh takes."""
     bench = bench or registry.load()
     cell = registry.workload(bench, workload)
     config = config or registry.config(bench, cell["config"])
     traffic = traffic or registry.traffic(cell["traffic"])
-    devs = require_chips(int(cell["chips"])) if require_tpu else None
+    chips = int(cell["chips"])
+    if mesh_chips(config) != chips:
+        raise ValueError(
+            f"{workload} asks for {chips} chips; its configuration's "
+            f"shards take n_shards x n_replicas = {mesh_chips(config)}")
+    devs = require_chips(chips) if require_tpu else None
     import jax
 
     if devs is None:
@@ -745,7 +857,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
             f"build_s={opened['build_s']:.3f} "
             f"snapshot_s={opened['snapshot_s']:.3f} warm_s={warm_s:.3f} "
             f"cache={cache}")
-        trace_dir = str(OUT / "trace" / workload) if trace else None
+        traces = (workdir.parent if workdir else OUT) / "trace"
+        trace_dir = str(traces / workload) if trace else None
         win = s.run_window(sched, seconds, trace_dir=trace_dir)
         late_med, late_max = lateness_ms(win)
         log(f"window: requests={len(sched.t)} rate={traffic['rate_per_s']} "
@@ -754,18 +867,21 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         log("host: " + " ".join(f"{k}={v:.3f}" if isinstance(v, float)
                                 else f"{k}={v}" for k, v in win.host.items()))
         post = s.post_window()
-        roof = s.roofline_batches(str(OUT / "trace" / (workload + ".roof"))) \
+        if s.handle is not None:
+            log(f"shards: live_after_window={s.shard_live()}")
+        roof = s.roofline_batches(str(traces / (workload + ".roof"))) \
             if trace else None
         if roof:
             from bench.metrics._common import SCAN_KERNEL
             from bench.peaks import peaks
             pk = peaks(devs[0].device_kind)
+            kernel = trace_reduce.kernel_s(roof["events"], SCAN_KERNEL, chips)
             log(f"roofline: bytes={roof['bytes']:.0f} flops={roof['flops']:.0f} "
-                f"bytes_bound_s={roof['bytes'] / pk['hbm_bytes_per_s']:.6f} "
-                f"flops_bound_s={roof['flops'] / pk['flops_bf16']:.6f} "
-                f"kernel_s={trace_reduce.kernel_s(roof['events'], SCAN_KERNEL)[0]:.6f}")
-        stats = devs[0].memory_stats() or {}
-        peak = int(stats.get("peak_bytes_in_use", 0))
+                f"bytes_bound_s={roof['bytes'] / (pk['hbm_bytes_per_s'] * chips):.6f} "
+                f"flops_bound_s={roof['flops'] / (pk['flops_bf16'] * chips):.6f} "
+                f"kernel_s={kernel[0]:.6f} chips={chips}")
+        peaks_b = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                   for d in devs[:chips]]
         s.close()
         t = time.perf_counter()
         log(f"after: post_and_roof_s={t - win.close:.3f}")
@@ -786,7 +902,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     finally:
         s.close()
     r = readings(s, win, setup_s=setup_s, recall=result["recall_at_10"],
-                 roof=roof, device_kind=devs[0].device_kind)
+                 roof=roof, device_kind=devs[0].device_kind, chips=chips)
     lat = r["lat_ms"]
     log("latency: " + " ".join(
         f"{k}_p{q}_ms={np.percentile(v, q):.3f}"
@@ -811,17 +927,21 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         checks[name] = {"value": value, "limit": float(limits[name])}
     checks["unanswered"] = {"value": float(lost), "limit": 0.0}
     correct = all(c["value"] <= c["limit"] for c in checks.values())
+    # the fullest of the cell's devices, each device's peak beside it
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
-              "count": len(devs), "memory_peak_bytes": peak}
+              "count": len(devs), "memory_peak_bytes": max(peaks_b),
+              "memory_peak_bytes_per_device": peaks_b}
     out = {"correct": bool(correct), "attempted": attempted, "failed": failed,
            "metrics": metrics, "device": device}
     out["lateness_ms"] = {"median": late_med, "max": late_max}
     out["host"] = win.host
     if trace and win.trace_bounds is not None:
         lo, hi = win.trace_bounds
-        device["busy_s"] = trace_reduce.busy_s(win.trace_events, lo, hi)
+        device["busy_s"] = trace_reduce.busy_s(win.trace_events, lo, hi,
+                                               chips)
         device["window_s"] = (hi - lo) / 1e9
-        out["breakdown"] = trace_reduce.breakdown(win.trace_events, lo, hi)
+        out["breakdown"] = trace_reduce.breakdown(win.trace_events, lo, hi,
+                                                  chips=chips)
     for name in ("control_int4", "control_stale", "control_refuse"):
         if name in result:
             out[name] = result[name]

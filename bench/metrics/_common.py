@@ -29,29 +29,32 @@ def update_p90_ms(r: dict) -> float | None:
 
 
 def scan_roofline(r: dict) -> float | None:
-    """Percent of the least time the chip could take for the traced
-    batches' scan work (the larger of needed bytes over HBM bandwidth and
-    needed operations over peak FLOP/s) in the scan kernel's device time."""
+    """Percent of the least time the cell's chips could take for the
+    traced batches' scan work (the larger of needed bytes over their HBM
+    bandwidth and needed operations over their peak FLOP/s, each one
+    chip's peak times the chips) in the scan kernel's device time
+    averaged over those chips.  The work is summed over the shards."""
     roof = r.get("roof")
     if not roof:
         return None
-    kernel, n = trace_reduce.kernel_s(roof["events"], SCAN_KERNEL)
+    chips = r.get("chips", 1)
+    kernel, n = trace_reduce.kernel_s(roof["events"], SCAN_KERNEL, chips)
     if n == 0 or kernel <= 0:
         return None
     pk = peaks.peaks(r["device_kind"])
-    least = max(roof["bytes"] / pk["hbm_bytes_per_s"],
-                roof["flops"] / pk["flops_bf16"])
+    least = max(roof["bytes"] / (pk["hbm_bytes_per_s"] * chips),
+                roof["flops"] / (pk["flops_bf16"] * chips))
     return 100.0 * least / kernel
 
 
 def device_idle_share(r: dict) -> float | None:
     """Percent of the traced slice of the window in which no operation ran
-    on the device."""
+    on the device, averaged over the cell's chips."""
     ev, bounds = r.get("trace_events"), r.get("trace_bounds")
     if not ev or not bounds:
         return None
     lo, hi = bounds
-    busy = trace_reduce.busy_s(ev, lo, hi)
+    busy = trace_reduce.busy_s(ev, lo, hi, r.get("chips", 1))
     if busy <= 0:
         return None
     return 100.0 * (1.0 - busy / ((hi - lo) / 1e9))

@@ -9,9 +9,11 @@ that list, so a small recorded trace can be checked into the tests.
   device (the ``XLA Ops`` line of each ``/device:TPU:<n>`` plane), clipped
   to the traced slice and averaged over the chips used.
 * Kernel time is the sum of the durations of the device operations whose
-  name contains the kernel's name.
-* Each idle gap is named by what the host was doing in it: the shortest
-  host event that covers the gap's middle.
+  name contains the kernel's name, averaged over the chips used.
+* The breakdown's operation times are averaged over the chips used; its
+  idle gaps are those of the first of them, and each is named by what
+  the host was doing in it: the shortest host event that covers the
+  gap's middle.
 """
 from __future__ import annotations
 
@@ -112,18 +114,23 @@ def kernel_s(events, pattern: str, chips: int = 1) -> tuple[float, int]:
     return (tot / len(ops) / 1e9 if ops else 0.0), n
 
 
-def breakdown(events, lo: int, hi: int, top: int = 10) -> dict:
-    """The device operations that took most time and the longest idle
-    gaps, each gap named by the host's activity at its middle."""
-    ops = device_ops(events, 1)
+def breakdown(events, lo: int, hi: int, top: int = 10,
+              chips: int = 1) -> dict:
+    """The device operations that took most time (averaged over the
+    chips used) and the longest idle gaps of the first chip, each gap
+    named by the host's activity at its middle.  Over several chips
+    ``gaps_chip`` names the chip the gaps are from."""
+    ops = device_ops(events, chips)
     if not ops:
         return {"device_ops": [], "idle_gaps": []}
-    evs = next(iter(ops.values()))
     per_op: dict[str, int] = {}
-    for name, s, d in evs:
-        if s + d > lo and s < hi:
-            per_op[op_name(name)] = per_op.get(op_name(name), 0) + d
+    for evs in ops.values():
+        for name, s, d in evs:
+            if s + d > lo and s < hi:
+                per_op[op_name(name)] = per_op.get(op_name(name), 0) + d
     top_ops = sorted(per_op.items(), key=lambda kv: -kv[1])[:top]
+    gaps_chip = next(iter(ops))
+    evs = ops[gaps_chip]
     busy = _merged(((s, s + d) for _, s, d in evs), lo, hi)
     gaps, prev = [], lo
     for s, e in busy:
@@ -141,5 +148,8 @@ def breakdown(events, lo: int, hi: int, top: int = 10) -> dict:
         cover = [(d, n) for n, hs, d in host if hs <= mid < hs + d]
         named.append([min(cover)[1] if cover else "no host event",
                       (e - s) / 1e9])
-    return {"device_ops": [[n, d / 1e9] for n, d in top_ops],
-            "idle_gaps": named}
+    out = {"device_ops": [[n, d / len(ops) / 1e9] for n, d in top_ops],
+           "idle_gaps": named}
+    if len(ops) > 1:
+        out["gaps_chip"] = gaps_chip
+    return out
